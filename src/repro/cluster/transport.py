@@ -649,6 +649,8 @@ def _worker_entry(conn, spec: BackendSpec, cfg: ReplicaConfig,
                   rid: int) -> None:
     """Entry point of a spawned pipe-replica worker process."""
     from repro.cluster.metrics import set_worker_registry
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     registry = MetricsRegistry()
     set_worker_registry(registry)   # builders adopt the heartbeat registry
     # follower-mode tracer: sample_rate=0 means the worker never roots a

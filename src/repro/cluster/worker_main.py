@@ -100,6 +100,8 @@ def run_worker(address: Tuple[str, int], token: str,
                connect_window_s: float = 30.0,
                protocol_version: int = PROTOCOL_VERSION) -> None:
     """Connect-serve-reconnect until crashed, drained, or rejected."""
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     address = (str(address[0]), int(address[1]))
     store = ArtifactStore(artifacts_dir)
     registry = MetricsRegistry()

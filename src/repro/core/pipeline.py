@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.filtering import Compacted, compact_by_score
-from repro.core.sharding import shard_map_compat
 from repro.core import joins
 from repro.models import svm as svm_mod
 
@@ -79,7 +78,7 @@ def _phase2_local(models, claims: Compacted, evid: Compacted,
     if pcfg.use_pair_kernel:
         from repro.kernels import ops as kops
         scores = kops.pair_score(models["link"], claims.feats, evid.feats,
-                                 interpret=True)
+                                 interpret=kops.use_interpret())
     else:
         scores = svm_mod.link_score_matrix(models["link"], claims.feats,
                                            evid.feats)
@@ -135,8 +134,8 @@ def make_batch_step(pcfg: PipelineConfig, mesh: Optional[Mesh] = None,
         link_scores=P(None, data_axis), pair_valid=P(None, data_axis),
         claim_index=P(), evid_index=P(data_axis),
         claim_keys=P(), evid_keys=P(data_axis), n_dropped=P())
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(P(), dspec, dspec),
-                          out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), dspec, dspec),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

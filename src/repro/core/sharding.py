@@ -55,21 +55,6 @@ def param_leaf(x) -> bool:
     return isinstance(x, Param)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: import location moved and the
-    replication-check kwarg was renamed (``check_rep`` -> ``check_vma``)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:                                # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except TypeError:                                  # pre-rename jax
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
 def split_params(tree):
     """Split a tree of :class:`Param` into (values, logical_axes) trees."""
     values = jax.tree_util.tree_map(lambda p: p.value, tree, is_leaf=param_leaf)

@@ -50,12 +50,8 @@ def decode_attention_bhd(q, k, v, lengths, *, n_splits: int = 8,
     kernel = functools.partial(_decode_kernel, ls=ls,
                                scale=1.0 / math.sqrt(hd))
     grid = (B, H, n_splits)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"))
-    except Exception:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"))
+    cparams = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel"))
     acc, ml = pl.pallas_call(
         kernel,
         grid=grid,

@@ -94,14 +94,9 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                                bq=bq, bk=bk, s_valid=S,
                                scale=1.0 / math.sqrt(hd))
     grid = (B, H, nq, nk)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except Exception:  # older API spelling
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+    cparams = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
     out = pl.pallas_call(
         kernel,
         grid=grid,

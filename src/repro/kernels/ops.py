@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) they run with interpret=True and are validated
-against ref.py / the pure-jnp model paths; on TPU interpret=False lowers to
-Mosaic.
+Call sites pass ``interpret=use_interpret()``: the kernels lower to Mosaic
+on a TPU backend and run in the Pallas interpreter on any other (the CPU
+test suite validates them there against ref.py / the pure-jnp model
+paths).
 """
 from __future__ import annotations
 
@@ -17,6 +18,12 @@ from repro.kernels.paged_attention import (paged_decode_attention_bkgd,
                                            paged_extend_attention_bkgd)
 from repro.kernels.pair_score import pair_score_blocked
 from repro.kernels.ssm_scan import ssm_scan_blocked
+
+
+def use_interpret() -> bool:
+    """True unless the default backend is a TPU: Pallas TPU kernels only
+    compile for a TPU, and everywhere else they run interpreted."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -47,14 +54,14 @@ def decode_attention(q, k, v, lengths, *, n_splits: int = 8,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            interpret: bool = False):
-    """q: (B,H,hd); k_pool/v_pool: (num_blocks, bs, KV, hd) shared pools;
+    """q: (B,H,hd); k_pool/v_pool: (num_blocks, KV, bs, hd) shared pools;
     block_tables: (B, nb); lengths: (B,) -> (B,H,hd).
 
     The kernel gathers K/V through the block table inside the grid (scalar
     prefetch resolves physical pool rows), so no dense per-sequence cache
     is ever materialized."""
     B, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     G = H // KV
     out = paged_decode_attention_bkgd(q.reshape(B, KV, G, hd),
                                       k_pool, v_pool, block_tables, lengths,
@@ -66,7 +73,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 def paged_extend_attention(q, k_pool, v_pool, block_tables, pos0, *,
                            interpret: bool = False):
     """q: (B,S,H,hd) suffix queries at absolute positions ``pos0 + s``;
-    k_pool/v_pool: (num_blocks, bs, KV, hd) shared pools (suffix K/V
+    k_pool/v_pool: (num_blocks, KV, bs, hd) shared pools (suffix K/V
     already scattered in); block_tables: (B, nb); pos0: (B,)
     -> (B,S,H,hd).
 
@@ -75,11 +82,13 @@ def paged_extend_attention(q, k_pool, v_pool, block_tables, pos0, *,
     tables scalar-prefetched, masked like the dense oracle
     (key p visible to query s iff p <= pos0 + s)."""
     B, S, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     G = H // KV
-    out = paged_extend_attention_bkgd(q.reshape(B, S, KV, G, hd),
+    qk = q.reshape(B, S, KV, G, hd).transpose(0, 2, 1, 3, 4)
+    out = paged_extend_attention_bkgd(qk.reshape(B, KV, S * G, hd),
                                       k_pool, v_pool, block_tables, pos0,
-                                      interpret=interpret)
+                                      G=G, interpret=interpret)
+    out = out.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, S, H, hd)
 
 
@@ -96,16 +105,19 @@ def pair_score(link_params, claims, evidence, *, block_n: int = 128,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
-def ssm_scan(xc, dt, Bc, Cc, A, D, h0=None, *, chunk: int = 64,
+def ssm_scan(xc, dt, Bc, Cc, A, D, h0=None, *, chunk: int = 32,
              block_d: int = 512, interpret: bool = False):
-    """Same contract as models.ssm.selective_scan (returns (y, h_final))."""
+    """Same contract as models.ssm.selective_scan (returns (y, h_final)).
+
+    The recurrence runs in the kernel's lane-dense ``(B, S, N, D)`` layout
+    (channels on the 128-wide lane axis, the small state on sublanes)."""
     Bsz, S, di = xc.shape
-    a_bar = jnp.exp(dt[..., None] * A[None, None])
-    b_bar = (dt * xc)[..., None] * Bc[:, :, None, :]
+    a_bar = jnp.exp(dt[:, :, None, :] * A.T[None, None])      # (B,S,N,D)
+    b_bar = (dt * xc)[:, :, None, :] * Bc[..., None]
     if h0 is None:
         h0 = jnp.zeros((Bsz, di, A.shape[-1]), jnp.float32)
-    h_seq, h_fin = ssm_scan_blocked(a_bar, b_bar, h0, chunk=chunk,
-                                    block_d=min(block_d, di),
+    h_seq, h_fin = ssm_scan_blocked(a_bar, b_bar, h0.transpose(0, 2, 1),
+                                    chunk=chunk, block_d=min(block_d, di),
                                     interpret=interpret)
-    y = jnp.einsum("bsdn,bsn->bsd", h_seq, Cc) + xc * D[None, None]
-    return y, h_fin
+    y = jnp.einsum("bsnd,bsn->bsd", h_seq, Cc) + xc * D[None, None]
+    return y, h_fin.transpose(0, 2, 1)

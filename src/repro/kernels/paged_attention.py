@@ -1,21 +1,22 @@
-"""Pallas TPU paged decode attention: single-query attention over a
-block-pooled KV cache, gathered *inside* the kernel through a per-sequence
-block table.
+"""Pallas TPU paged attention: decode (one query per sequence) and extend
+(a suffix of queries per sequence) over a block-pooled KV cache, gathered
+*inside* the kernel through a per-sequence block table.
 
-The dense flash-decode kernel (``decode_attention.py``) reads a contiguous
-``(B, L, KV, hd)`` cache; here K/V live in one shared pool
-``(num_blocks, block_size, KV, hd)`` and each sequence names its blocks in
-``block_tables (B, nb)``.  The block table and the valid lengths ride in as
-*scalar prefetch* operands, so the grid's last (sequential) dimension walks
-a sequence's blocks and the BlockSpec ``index_map`` resolves the physical
-pool row **before** the kernel body runs — the DMA engine fetches exactly
-the blocks the sequence owns, never a dense ``max_len`` stripe.  Per-block
-``(m, l, acc)`` partials accumulate across the sequential grid dimension in
-VMEM scratch (the standard online-softmax pattern), and blocks past the
-sequence's length are skipped entirely with ``@pl.when``.
+K/V live in one shared pool per layer, ``(num_blocks, KV, block_size,
+hd)``, and each sequence names its blocks in ``block_tables (B, nb)``.  A
+block's last two axes are ``(block_size, hd)``, which is the tiling the TPU
+compiler accepts for a per-block DMA.  The block table and the valid
+lengths ride in as *scalar prefetch* operands, so the grid's last
+(sequential) dimension walks a sequence's blocks and the BlockSpec
+``index_map`` resolves the physical pool row **before** the kernel body
+runs — the DMA engine fetches exactly the blocks the sequence owns, never a
+dense ``max_len`` stripe.  Per-block ``(m, l, acc)`` partials accumulate
+across the sequential grid dimension in VMEM scratch (the standard
+online-softmax pattern), and blocks past the sequence's length are skipped
+with ``@pl.when``.
 
-On CPU (tests) this runs with ``interpret=True`` against
-``ref.paged_decode_attention_ref``.
+The oracles are ``ref.paged_decode_attention_ref`` and
+``ref.paged_extend_attention_ref``.
 """
 from __future__ import annotations
 
@@ -32,87 +33,87 @@ NEG_INF = -2.0e38
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, bs: int, scale: float):
-    """Grid (B, KV, nb); the last dimension is sequential per (b, h).
+    """Grid (B, nb); the block dimension is sequential per sequence.
 
-    q_ref: (1, 1, G, hd) queries of this kv head's group
-    k_ref/v_ref: (1, bs, 1, hd) — the pool block named by bt[b, j]
-    o_ref: (1, 1, G, hd); m/l/acc: VMEM scratch carried across j.
+    One step fetches a whole pool block, every kv head of it, and runs the
+    online-softmax update for each head's query group.
+
+    q_ref: (1, KV, G, hd); k_ref/v_ref: (1, KV, bs, hd) — the pool block
+    named by bt[b, j]; o_ref: (1, KV, G, hd); m/l: (KV, G, 128) (column 0
+    used), acc: (KV, G, hd) — VMEM scratch carried across j.
     """
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    n_blocks = pl.num_programs(2)
+    j = pl.program_id(1)
+    n_blocks = pl.num_programs(1)
     length = len_ref[b]
+    n_kv = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(j * bs < length)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)                   # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (bs, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(pos < length, s, NEG_INF)               # (G, bs)
-        m_prev = m_ref[:, :1]                                 # (G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * corr + \
-            jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)                # (bs, hd)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)               # (G, hd)
+            k = k_ref[0, h].astype(jnp.float32)               # (bs, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(pos < length, s * scale, NEG_INF)   # (G, bs)
+            m_prev = m_ref[h][:, :1]                          # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = jnp.broadcast_to(
+                l_ref[h][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+                l_ref.shape[1:])
+            v = v_ref[0, h].astype(jnp.float32)               # (bs, hd)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
 
     @pl.when(j == n_blocks - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[:] /
-                       jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_bkgd(q, k_pool, v_pool, block_tables, lengths, *,
                                 interpret: bool = False):
-    """q: (B, KV, G, hd); k_pool/v_pool: (num_blocks, bs, KV, hd);
+    """q: (B, KV, G, hd); k_pool/v_pool: (num_blocks, KV, bs, hd);
     block_tables: (B, nb) int32; lengths: (B,) int32 -> (B, KV, G, hd)."""
     B, KV, G, hd = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nb = block_tables.shape[1]
     kernel = functools.partial(_paged_decode_kernel, bs=bs,
                                scale=1.0 / math.sqrt(hd))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # block_tables, lengths
-        grid=(B, KV, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, KV, G, hd), lambda b, j, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, bs, hd),
+                         lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, KV, bs, hd),
+                         lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, hd),
+                               lambda b, j, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),       # running max (col 0)
-            pltpu.VMEM((G, 128), jnp.float32),       # running sum (col 0)
-            pltpu.VMEM((G, hd), jnp.float32),        # output accumulator
+            pltpu.VMEM((KV, G, 128), jnp.float32),   # running max (col 0)
+            pltpu.VMEM((KV, G, 128), jnp.float32),   # running sum (col 0)
+            pltpu.VMEM((KV, G, hd), jnp.float32),    # output accumulator
         ],
     )
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=cparams,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q, k_pool, v_pool)
@@ -128,28 +129,27 @@ def _paged_extend_kernel(bt_ref, pos0_ref, q_ref, k_ref, v_ref, o_ref,
     softmax over the prefix blocks *and* the in-flight suffix (already
     scattered into the pool), masked causally over absolute positions —
     key position p is visible to query s iff ``p <= pos0[b] + s``, the
-    dense oracle's mask.  Scratch rows are the S*G flattened
-    (query, group-head) pairs carried across j.
+    dense oracle's mask.  Rows are the S*G (query, group-head) pairs of
+    one kv head, query-major, carried across j.
 
-    q_ref: (1, S, 1, G, hd); k_ref/v_ref: (1, bs, 1, hd) — pool block
-    bt[b, j]; o_ref: (1, S, 1, G, hd).
+    q_ref: (1, 1, S*G, hd); k_ref/v_ref: (1, 1, bs, hd) — head h of pool
+    block bt[b, j]; o_ref: (1, 1, S*G, hd).
     """
     b = pl.program_id(0)
     j = pl.program_id(2)
     n_blocks = pl.num_programs(2)
     p0 = pos0_ref[b]
-    hd = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(j * bs < p0 + S)
     def _block():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(S * G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (bs, hd)
+        q = q_ref[0, 0].astype(jnp.float32)                   # (S*G, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (bs, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         key_pos = j * bs + jax.lax.broadcasted_iota(
@@ -161,30 +161,32 @@ def _paged_extend_kernel(bt_ref, pos0_ref, q_ref, k_ref, v_ref, o_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * corr + \
-            jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)                # (bs, hd)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot(
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        v = v_ref[0, 0].astype(jnp.float32)                   # (bs, hd)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(j == n_blocks - 1)
     def _finish():
-        o_ref[0, :, 0] = (acc_ref[:] /
-                          jnp.maximum(l_ref[:, :1], 1e-30)
-                          ).reshape(S, G, hd).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] /
+                       jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_extend_attention_bkgd(q, k_pool, v_pool, block_tables, pos0, *,
-                                interpret: bool = False):
-    """q: (B, S, KV, G, hd) suffix queries; k_pool/v_pool:
-    (num_blocks, bs, KV, hd); block_tables: (B, nb) int32; pos0: (B,)
-    int32 absolute position of each row's first query
-    -> (B, S, KV, G, hd).  Suffix K/V must already be scattered into the
-    pool (the kernel reads them back through the table like any prefix
-    block — one code path, no separate in-flight operand)."""
-    B, S, KV, G, hd = q.shape
-    bs = k_pool.shape[1]
+                                G: int, interpret: bool = False):
+    """q: (B, KV, S*G, hd) suffix queries, rows query-major (row r is
+    query r // G of group head r % G); k_pool/v_pool: (num_blocks, KV, bs,
+    hd); block_tables: (B, nb) int32; pos0: (B,) int32 absolute position
+    of each row's first query -> (B, KV, S*G, hd).  Suffix K/V must already
+    be scattered into the pool (the kernel reads them back through the
+    table like any prefix block — one code path, no separate in-flight
+    operand)."""
+    B, KV, SG, hd = q.shape
+    S = SG // G
+    bs = k_pool.shape[2]
     nb = block_tables.shape[1]
     kernel = functools.partial(_paged_extend_kernel, bs=bs, S=S, G=G,
                                scale=1.0 / math.sqrt(hd))
@@ -192,32 +194,27 @@ def paged_extend_attention_bkgd(q, k_pool, v_pool, block_tables, pos0, *,
         num_scalar_prefetch=2,           # block_tables, pos0
         grid=(B, KV, nb),
         in_specs=[
-            pl.BlockSpec((1, S, 1, G, hd),
-                         lambda b, h, j, bt, p0: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, bt, p0: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b, h, j, bt, p0: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, SG, hd),
+                         lambda b, h, j, bt, p0: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda b, h, j, bt, p0: (bt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda b, h, j, bt, p0: (bt[b, j], h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, S, 1, G, hd),
-                               lambda b, h, j, bt, p0: (b, 0, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, SG, hd),
+                               lambda b, h, j, bt, p0: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((S * G, 128), jnp.float32),   # running max (col 0)
-            pltpu.VMEM((S * G, 128), jnp.float32),   # running sum (col 0)
-            pltpu.VMEM((S * G, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((SG, 128), jnp.float32),      # running max (col 0)
+            pltpu.VMEM((SG, 128), jnp.float32),      # running sum (col 0)
+            pltpu.VMEM((SG, hd), jnp.float32),       # output accumulator
         ],
     )
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, G, hd), q.dtype),
-        compiler_params=cparams,
+        out_shape=jax.ShapeDtypeStruct((B, KV, SG, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), pos0.astype(jnp.int32),
       q, k_pool, v_pool)

@@ -54,12 +54,8 @@ def pair_score_blocked(claims, evidence, W, w_c, w_e, bias, *,
         evidence = jnp.pad(evidence, ((0, pad_m), (0, 0)))
     grid = ((N + pad_n) // bn, (M + pad_m) // bm)
     kernel = functools.partial(_pair_kernel, bn=bn, bm=bm)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except Exception:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    cparams = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -48,10 +48,17 @@ def decode_attention_ref(q, k, v, lengths):
     return o.reshape(B, H, hd).astype(q.dtype)
 
 
+def _pool_gather(pool, block_tables):
+    """(num_blocks, KV, bs, hd) pool -> (B, nb*bs, KV, hd) virtual caches."""
+    B, nb = block_tables.shape
+    _, KV, bs, hd = pool.shape
+    return pool[block_tables].swapaxes(2, 3).reshape(B, nb * bs, KV, hd)
+
+
 def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths):
     """Single-query decode attention through a block table.
 
-    q: (B,H,hd); k_pool/v_pool: (num_blocks, bs, KV, hd) — the shared
+    q: (B,H,hd); k_pool/v_pool: (num_blocks, KV, bs, hd) — the shared
     device pool; block_tables: (B, nb) int32 physical block ids backing
     each sequence's virtual positions (padded with the null block);
     lengths: (B,) valid prefix length.  Returns (B,H,hd).
@@ -61,12 +68,8 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths):
     :func:`decode_attention_ref` — which is what makes it both the
     XLA fallback inside the model and the oracle for the Pallas kernel.
     """
-    B = q.shape[0]
-    bs = k_pool.shape[1]
-    nb = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
-    v = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
-    return decode_attention_ref(q, k, v, lengths)
+    return decode_attention_ref(q, _pool_gather(k_pool, block_tables),
+                                _pool_gather(v_pool, block_tables), lengths)
 
 
 def paged_extend_attention_ref(q, k_pool, v_pool, block_tables, pos0):
@@ -78,16 +81,14 @@ def paged_extend_attention_ref(q, k_pool, v_pool, block_tables, pos0):
     visible to query s iff ``p <= pos0 + s`` — causal over absolute
     positions, exactly the dense extend mask.  Returns (B,S,H,hd)."""
     B, S, H, hd = q.shape
-    bs = k_pool.shape[1]
-    nb = block_tables.shape[1]
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     G = H // KV
-    k = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
-    v = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
+    k = _pool_gather(k_pool, block_tables)
+    v = _pool_gather(v_pool, block_tables)
+    L = k.shape[1]
     qg = q.reshape(B, S, KV, G, hd)
     s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(hd)
-    L = nb * bs
     positions = pos0[:, None] + jnp.arange(S)[None, :]
     ok = jnp.arange(L)[None, None, :] <= positions[:, :, None]
     s = jnp.where(ok[:, None, None], s, NEG_INF)

@@ -1,10 +1,13 @@
 """Pallas TPU chunked selective-scan kernel (Mamba / RG-LRU style diagonal
 recurrence  h_t = a_t * h_{t-1} + b_t).
 
-Grid (B, n_channel_blocks, n_chunks) with the chunk dimension sequential:
-the carry h lives in VMEM scratch across chunks; within a chunk the
-recurrence closes with an associative scan over the loaded block, so the
-sequential depth is n_chunks, not S.
+Layout ``(B, S, N, D)``: the channel axis D is the 128-wide lane axis and
+the small state axis N sits on sublanes, so a ``(1, chunk, N, bD)`` block
+carries no lane padding (with N on lanes a state of 16 would pad to 128
+and multiply the block's VMEM by eight).  Grid (B, n_channel_blocks,
+n_chunks) with the chunk dimension sequential: the carry h lives in VMEM
+scratch across chunks, and within a chunk a loop walks the steps one at a
+time, each an elementwise ``(N, bD)`` update.
 """
 from __future__ import annotations
 
@@ -23,28 +26,26 @@ def _scan_kernel(a_ref, b_ref, h0_ref, hs_ref, hT_ref, h_scr, *, chunk: int):
     def _init():
         h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[0].astype(jnp.float32)                     # (chunk, bD, N)
-    b = b_ref[0].astype(jnp.float32)
+    def step(t, h):                                      # h: (N, bD)
+        h = a_ref[0, t].astype(jnp.float32) * h + \
+            b_ref[0, t].astype(jnp.float32)
+        hs_ref[0, t] = h.astype(hs_ref.dtype)
+        return h
 
-    def combine(l, r):
-        al, bl = l
-        ar, br = r
-        return al * ar, bl * ar + br
-
-    acc_a, acc_b = jax.lax.associative_scan(combine, (a, b), axis=0)
-    h_all = acc_a * h_scr[...][None] + acc_b             # (chunk, bD, N)
-    hs_ref[0] = h_all.astype(hs_ref.dtype)
-    h_scr[...] = h_all[-1]
+    h_scr[...] = jax.lax.fori_loop(0, chunk, step, h_scr[...])
 
     @pl.when(c == pl.num_programs(2) - 1)
     def _emit():
         hT_ref[0] = h_scr[...].astype(hT_ref.dtype)
 
 
-def ssm_scan_blocked(a_bar, b_bar, h0, *, chunk: int = 64,
+def ssm_scan_blocked(a_bar, b_bar, h0, *, chunk: int = 32,
                      block_d: int = 512, interpret: bool = False):
-    """a_bar,b_bar: (B,S,D,N) fp32; h0: (B,D,N).  Returns (h_seq, h_final)."""
-    B, S, D, N = a_bar.shape
+    """a_bar,b_bar: (B,S,N,D) fp32; h0: (B,N,D).  Returns (h_seq, h_final).
+
+    VMEM: a, b and h_seq blocks of ``chunk * N * bD * 4`` bytes each, double
+    buffered — 6 MiB at chunk 32, N 16, bD 512."""
+    B, S, N, D = a_bar.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk
     if pad:
@@ -55,30 +56,25 @@ def ssm_scan_blocked(a_bar, b_bar, h0, *, chunk: int = 64,
     nc = (S + pad) // chunk
     grid = (B, D // bD, nc)
     kernel = functools.partial(_scan_kernel, chunk=chunk)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        cparams = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     hs, hT = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, bD, N), lambda b, d, c: (b, c, d, 0)),
-            pl.BlockSpec((1, chunk, bD, N), lambda b, d, c: (b, c, d, 0)),
-            pl.BlockSpec((1, bD, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((1, chunk, N, bD), lambda b, d, c: (b, c, 0, d)),
+            pl.BlockSpec((1, chunk, N, bD), lambda b, d, c: (b, c, 0, d)),
+            pl.BlockSpec((1, N, bD), lambda b, d, c: (b, 0, d)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, bD, N), lambda b, d, c: (b, c, d, 0)),
-            pl.BlockSpec((1, bD, N), lambda b, d, c: (b, d, 0)),
+            pl.BlockSpec((1, chunk, N, bD), lambda b, d, c: (b, c, 0, d)),
+            pl.BlockSpec((1, N, bD), lambda b, d, c: (b, 0, d)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S + pad, D, N), jnp.float32),
-            jax.ShapeDtypeStruct((B, D, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, S + pad, N, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, N, D), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bD, N), jnp.float32)],
-        compiler_params=cparams,
+        scratch_shapes=[pltpu.VMEM((N, bD), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_bar, b_bar, h0)
     return hs[:, :S], hT

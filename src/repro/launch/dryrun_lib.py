@@ -263,8 +263,6 @@ def _compile_cell(cfg, sc, mesh, policy, accum_steps: int = 1):
 
 def _extract_cost(compiled) -> Dict[str, Any]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):          # older jax: one dict per program
-        ca = ca[0] if ca else {}
     colls = parse_collectives(compiled.as_text())
     by_op: Dict[str, float] = {}
     for c in colls:

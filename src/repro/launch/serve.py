@@ -15,10 +15,17 @@ control and unified metrics.  ``--transport`` picks replica placement:
     reaches this process — heartbeat-timeout crash detection and
     artifact-store weight fetch included.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch falcon-mamba-7b \
-        --requests 8 --max-new 16
-    PYTHONPATH=src python -m repro.launch.serve --replicas 2 \
-        --router-policy least_loaded --requests 8 --transport socket
+The model is served at its published widths with seeded random weights;
+``--reduced`` selects the tiny same-family preset the CPU tests use.  With
+thread replicas on an accelerator host each replica serves from a device
+of its own, and asking for more replicas than devices fails at start.
+
+    PYTHONPATH=src python -m repro.launch.serve --requests 8 --max-new 16
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced \
+        --replicas 2 --router-policy least_loaded --requests 8 \
+        --transport socket
+
+The process exits non-zero when any request ends without completing.
 """
 from __future__ import annotations
 
@@ -41,6 +48,7 @@ from repro.cluster import (AdmissionConfig, AdmissionController,
 from repro.cluster.tracing import start_profiling, stop_profiling
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import reduced as reduce_cfg
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import api
 from repro.serving import Engine, ServeConfig, make_engine_fns
 
@@ -107,11 +115,48 @@ def _start_telemetry(args, snapshot_fn, registry, router=None):
     return finalize
 
 
+def replica_devices(n: int):
+    """The device each of ``n`` in-process replicas serves from: a chip of
+    its own on an accelerator host, ``None`` (the default device, shared)
+    on the CPU backend."""
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        return [None] * n
+    if n > len(devs):
+        raise ValueError(f"--replicas {n} exceeds the {len(devs)} "
+                         f"{devs[0].platform} devices of this host: each "
+                         f"replica needs a device of its own")
+    return devs[:n]
+
+
+def _incomplete(finish_reasons, outs):
+    """Requests that ended without completing: a cluster result that is not
+    a token list, or an engine error / ``rejected_*`` finish."""
+    bad = [f"request {i}: finish_reason={r!r}"
+           for i, r in enumerate(finish_reasons)
+           if r == "error" or r.startswith("rejected_")]
+    bad += [f"request {i}: {type(o).__name__} {o!r}"[:200]
+            for i, o in enumerate(outs) if not isinstance(o, list)]
+    return bad
+
+
 def main(argv=None):
+    """Serve seeded requests; returns ``{"cfg", "params", "prompts",
+    "outputs", "wall_s", "replicas"}`` (``outputs[i]``: the tokens served
+    for ``prompts[i]``; ``replicas``: per thread replica, its device and
+    how many requests it finished)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b",
                     choices=[a for a in ARCH_IDS if a != "whisper-base"])
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family preset "
+                         "(configs.base.reduced, for CPU runs) instead of "
+                         "the published widths")
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--min-prompt", type=int, default=4,
+                    help="shortest seeded prompt, in tokens")
+    ap.add_argument("--max-prompt", type=int, default=15,
+                    help="longest seeded prompt, in tokens")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
@@ -222,7 +267,10 @@ def main(argv=None):
     if args.profile_dir:
         start_profiling(args.profile_dir)
 
-    cfg = reduce_cfg(get_config(args.arch))
+    cache_dir = setup_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduce_cfg(cfg)
     # remote workers init/load their own weights; don't pay for a parent copy
     need_params = args.replicas <= 1 or \
         args.transport not in ("process", "socket")
@@ -236,7 +284,9 @@ def main(argv=None):
                        swap_tier=args.swap_tier)
     rng = np.random.RandomState(args.seed)
     prompts = [rng.randint(0, cfg.vocab,
-                           size=rng.randint(4, 16)).astype(np.int32)
+                           size=rng.randint(args.min_prompt,
+                                            args.max_prompt + 1)
+                           ).astype(np.int32)
                for _ in range(args.requests)]
 
     snap = None
@@ -253,6 +303,10 @@ def main(argv=None):
         t0 = time.perf_counter()
         eng.run_until_drained()
         wall = time.perf_counter() - t0
+        outs = [r.out_tokens for r in reqs]
+        finish = [r.finish_reason for r in reqs]
+        replicas = [{"device": str(jax.devices()[0]),
+                     "served": len(eng.finished)}]
         toks = sum(len(r.out_tokens) for r in reqs)
         lats = [r.done_t - r.submit_t for r in reqs]
         if finalize_stats is not None:
@@ -270,9 +324,10 @@ def main(argv=None):
                         brownout=BrownoutController() if args.brownout
                         else None)
         rcfg = ReplicaConfig(max_batch=args.slots)
+        engines = []
         if args.transport in ("process", "socket"):
             spec = engine_spec(arch=args.arch, max_len=args.max_len,
-                               slots=args.slots, reduce=True, seed=0,
+                               slots=args.slots, reduce=args.reduced, seed=0,
                                weights_path=args.weights_dir,
                                fused=args.fused, sync_every=args.sync_every,
                                temperature=args.temperature,
@@ -287,11 +342,18 @@ def main(argv=None):
                                    transport=args.transport)
         else:
             shared_fns = make_engine_fns(cfg, scfg)
-            for _ in range(args.replicas):
-                router.add_replica(
-                    EngineBackend(Engine(params, cfg, scfg, metrics=metrics,
-                                         shared_fns=shared_fns)),
-                    rcfg)
+            for dev in replica_devices(args.replicas):
+                if dev is None:
+                    eng = Engine(params, cfg, scfg, metrics=metrics,
+                                 shared_fns=shared_fns)
+                else:
+                    # the replica's weights and device state live on its
+                    # own chip; jitted calls follow the committed weights
+                    with jax.default_device(dev):
+                        eng = Engine(jax.device_put(params, dev), cfg, scfg,
+                                     metrics=metrics, shared_fns=shared_fns)
+                engines.append((dev, eng))
+                router.add_replica(EngineBackend(eng), rcfg)
         if stats_on:
             finalize_stats = _start_telemetry(args, router.cluster_snapshot,
                                               metrics, router=router)
@@ -306,6 +368,10 @@ def main(argv=None):
         if finalize_stats is not None:
             finalize_stats()
         router.stop()
+        finish = [""] * len(outs)
+        replicas = [{"device": str(dev if dev is not None
+                                   else jax.devices()[0]),
+                     "served": len(eng.finished)} for dev, eng in engines]
         toks = sum(len(o) for o in outs if isinstance(o, list))
         lats = [r.finished_s - r.submitted_s for r in creqs]
         snap = metrics.snapshot()
@@ -315,9 +381,13 @@ def main(argv=None):
               f"completed={snap['router.completed']:.0f} "
               f"shed={snap.get('admission.shed_queue_full', 0):.0f}")
 
-    print(f"[serve] arch={args.arch} reqs={len(prompts)} tokens={toks} "
+    dev = jax.devices()[0] if need_params else None
+    where = f"{dev.platform}:{dev.device_kind}" if dev is not None \
+        else f"{args.transport} workers"
+    print(f"[serve] arch={args.arch} reduced={args.reduced} on={where} "
+          f"reqs={len(prompts)} tokens={toks} "
           f"tok/s={toks / wall:.1f} p50={np.median(lats):.2f}s "
-          f"p99={np.percentile(lats, 99):.2f}s")
+          f"p99={np.percentile(lats, 99):.2f}s compile_cache={cache_dir}")
 
     if args.profile_dir:
         stop_profiling()
@@ -331,6 +401,12 @@ def main(argv=None):
         with open(args.prom_out, "w") as f:
             f.write(prometheus_text(snap or {}))
         print(f"[metrics] prometheus exposition -> {args.prom_out}")
+    bad = _incomplete(finish, outs)
+    if bad:
+        raise SystemExit(f"[serve] {len(bad)} of {len(prompts)} requests "
+                         f"did not complete: " + "; ".join(bad))
+    return {"cfg": cfg, "params": params, "prompts": prompts,
+            "outputs": outs, "wall_s": wall, "replicas": replicas}
 
 
 if __name__ == "__main__":

@@ -202,7 +202,6 @@ def flash_attention_jnp(q, k, v, *, causal: bool = True, window: int = 0,
 # "ship the model once, split the instances" — see EXPERIMENTS.md §Perf.
 def seqshard_attn_forward(params, x, cfg, *, kind: str, mesh, batch_axes):
     from jax.sharding import PartitionSpec as P
-    from repro.core.sharding import shard_map_compat
 
     B, S, _ = x.shape
     n = mesh.shape["model"]
@@ -243,11 +242,12 @@ def seqshard_attn_forward(params, x, cfg, *, kind: str, mesh, batch_axes):
         out = out.reshape(xl.shape[0], S_loc, -1) @ p["wo"]
         return out, k, v
 
-    fn = shard_map_compat(local_fn, mesh=mesh,
-                          in_specs=(P(), P(b_ax, "model", None)),
-                          out_specs=(P(b_ax, "model", None),
-                                     P(b_ax, "model", None, None),
-                                     P(b_ax, "model", None, None)))
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), P(b_ax, "model", None)),
+                       out_specs=(P(b_ax, "model", None),
+                                  P(b_ax, "model", None, None),
+                                  P(b_ax, "model", None, None)),
+                       check_vma=False)
     return fn(params, x)
 
 
@@ -280,7 +280,7 @@ def attn_forward(params, x, cfg, *, kind: str, positions=None, encoder_kv=None,
     if cfg.use_kernels and kind in ("causal", "global", "local") and S >= 128:
         from repro.kernels import ops as kops
         out = kops.flash_attention(q, k, v, causal=True, window=window,
-                                   interpret=True)
+                                   interpret=kops.use_interpret())
     elif S >= FLASH_MIN_SEQ:
         out = flash_attention_jnp(q, k, v, causal=kind != "bidir",
                                   window=window, softcap=cfg.attn_softcap)
@@ -468,16 +468,35 @@ def prefill_into_cache(params_unused, k, v, cache, cfg, *, kind: str):
 # block tables and never materializes a dense per-sequence cache.
 
 def init_paged_kv_cache(cfg, num_blocks: int, block_size: int):
-    """Per-layer block pool; ``num_blocks`` usable + 1 reserved null row."""
+    """Per-layer block pool ``(num_blocks + 1, KV, block_size, hd)``:
+    ``num_blocks`` usable + 1 reserved null row.  A block's last two axes
+    are (positions, head_dim), the tiling the Pallas kernels DMA."""
     KV, hd = cfg.n_kv_heads, cfg.head_dim
     return {
-        "kp": jnp.zeros((num_blocks + 1, block_size, KV, hd), cfg.act_dtype),
-        "vp": jnp.zeros((num_blocks + 1, block_size, KV, hd), cfg.act_dtype),
+        "kp": jnp.zeros((num_blocks + 1, KV, block_size, hd), cfg.act_dtype),
+        "vp": jnp.zeros((num_blocks + 1, KV, block_size, hd), cfg.act_dtype),
     }
 
 
 def is_paged_cache(cache) -> bool:
     return isinstance(cache, dict) and "kp" in cache
+
+
+def pool_rows(pool, bt):
+    """Pool ``(..., N, KV, bs, hd)`` gathered through a block table
+    ``bt (n, w)`` into dense-layout rows ``(..., n, w*bs, KV, hd)``."""
+    *lead, _, KV, bs, hd = pool.shape
+    n, w = bt.shape
+    rows = jnp.take(pool, bt, axis=len(lead))        # (..., n, w, KV, bs, hd)
+    return rows.swapaxes(-2, -3).reshape(*lead, n, w * bs, KV, hd)
+
+
+def pool_write(pool, phys, off, rows):
+    """``pool (N, KV, bs, hd)`` with ``rows (B, S, KV, hd)`` written at
+    block ``phys[b, s]``, offset ``off[b, s]``."""
+    # phys/off sit on either side of the KV slice, so the indexed rows come
+    # out as (B, S, KV, hd) — the layout of ``rows``
+    return pool.at[phys, :, off].set(rows.astype(pool.dtype))
 
 
 def _paged_scatter(cache, k, v, vpos, bt):
@@ -486,25 +505,21 @@ def _paged_scatter(cache, k, v, vpos, bt):
     k/v: (B, S, KV, hd); vpos: (B, S) virtual positions; bt: (B, nb).
     Positions beyond the table (prompt pads past ``nb*bs``) redirect to
     the null block."""
-    bs = cache["kp"].shape[1]
+    bs = cache["kp"].shape[-2]
     nb = bt.shape[1]
     vblock = vpos // bs
     phys = jnp.take_along_axis(bt, jnp.minimum(vblock, nb - 1), axis=1)
     phys = jnp.where(vblock < nb, phys, 0)
     off = vpos % bs
     cache = dict(cache)
-    cache["kp"] = cache["kp"].at[phys, off].set(k.astype(cache["kp"].dtype))
-    cache["vp"] = cache["vp"].at[phys, off].set(v.astype(cache["vp"].dtype))
+    cache["kp"] = pool_write(cache["kp"], phys, off, k)
+    cache["vp"] = pool_write(cache["vp"], phys, off, v)
     return cache
 
 
 def _paged_gather(cache, bt):
     """(B, nb*bs, KV, hd) virtual caches, materialized via the table."""
-    B, nb = bt.shape
-    bs = cache["kp"].shape[1]
-    k = cache["kp"][bt].reshape(B, nb * bs, *cache["kp"].shape[2:])
-    v = cache["vp"][bt].reshape(B, nb * bs, *cache["vp"].shape[2:])
-    return k, v
+    return pool_rows(cache["kp"], bt), pool_rows(cache["vp"], bt)
 
 
 def paged_attn_decode(params, x, cache, pos, bt, cfg, *, kind: str):
@@ -521,7 +536,8 @@ def paged_attn_decode(params, x, cache, pos, bt, cfg, *, kind: str):
     if cfg.use_kernels:
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(q[:, 0], cache["kp"], cache["vp"],
-                                          bt, pos + 1, interpret=True)
+                                          bt, pos + 1,
+                                          interpret=kops.use_interpret())
         out = out[:, None]
     else:
         kg, vg = _paged_gather(cache, bt)
@@ -550,7 +566,8 @@ def paged_attn_extend(params, x, cache, pos0, bt, cfg, *, kind: str):
         # — no dense per-sequence materialization
         from repro.kernels import ops as kops
         out = kops.paged_extend_attention(q, cache["kp"], cache["vp"], bt,
-                                          pos0, interpret=True)
+                                          pos0,
+                                          interpret=kops.use_interpret())
     else:
         kg, vg = _paged_gather(cache, bt)
         L = kg.shape[1]

@@ -124,7 +124,7 @@ def ssm_forward(params, x, cfg, state=None):
         from repro.kernels import ops as kops
         y, h_fin = kops.ssm_scan(xc.astype(jnp.float32), dt, Bc, Cc, A,
                                  params["D"].astype(jnp.float32),
-                                 h0=h0, interpret=True)
+                                 h0=h0, interpret=kops.use_interpret())
     else:
         y, h_fin = selective_scan(xc, dt, Bc, Cc, A, params["D"], h0=h0)
     y = (y.astype(x.dtype)) * jax.nn.silu(z)

@@ -106,7 +106,7 @@ def paged_supported(cfg, max_len: int) -> bool:
 
 
 def init_paged_caches(cfg, num_blocks: int, block_size: int):
-    """Block-pool caches: one shared ``(num_blocks+1, bs, KV, hd)`` K/V
+    """Block-pool caches: one shared ``(num_blocks+1, KV, bs, hd)`` K/V
     pool per layer (row 0 reserved as the null block) instead of a dense
     per-slot stripe.  Layout mirrors :func:`init_caches` so the scan
     machinery is unchanged."""
@@ -426,19 +426,10 @@ def gather_paged_virtual(caches, bt):
     the engine); the result leaves are ``{"k","v"} (R, B, nb*bs, KV, hd)``
     — exactly the layout :func:`init_caches` builds, so every dense
     decode path runs on them unchanged."""
-    B, nb = bt.shape
     out = []
     for gc in caches:
-        row = []
-        for c in gc:
-            bs = c["kp"].shape[2]
-            row.append({
-                "k": c["kp"][:, bt].reshape(c["kp"].shape[0], B, nb * bs,
-                                            *c["kp"].shape[3:]),
-                "v": c["vp"][:, bt].reshape(c["vp"].shape[0], B, nb * bs,
-                                            *c["vp"].shape[3:]),
-            })
-        out.append(row)
+        out.append([{"k": attn.pool_rows(c["kp"], bt),
+                     "v": attn.pool_rows(c["vp"], bt)} for c in gc])
     return out
 
 
@@ -452,21 +443,15 @@ def refresh_paged_virtual(virt, caches, bt_rows, slot_idx):
     writeback) and must NOT be re-read from it.  ``bt_rows (n, vw)`` is
     each admitted slot's table cut to the resident width; duplicate
     ``slot_idx`` entries (batch padding) write identical values."""
-    n, vw = bt_rows.shape
     out = []
     for gv, gc in zip(virt, caches):
         row = []
         for cv, c in zip(gv, gc):
-            bs = c["kp"].shape[2]
             row.append({
                 "k": cv["k"].at[:, slot_idx].set(
-                    c["kp"][:, bt_rows].reshape(
-                        c["kp"].shape[0], n, vw * bs, *c["kp"].shape[3:]
-                    ).astype(cv["k"].dtype)),
+                    attn.pool_rows(c["kp"], bt_rows).astype(cv["k"].dtype)),
                 "v": cv["v"].at[:, slot_idx].set(
-                    c["vp"][:, bt_rows].reshape(
-                        c["vp"].shape[0], n, vw * bs, *c["vp"].shape[3:]
-                    ).astype(cv["v"].dtype)),
+                    attn.pool_rows(c["vp"], bt_rows).astype(cv["v"].dtype)),
             })
         out.append(row)
     return out
@@ -484,7 +469,7 @@ def scatter_paged_back(caches, virt, bt, start, width: int, stop=None):
     width can't push another slot's junk tail into a still-shared
     (not-yet-COWed) block."""
     B, nb = bt.shape
-    bs = caches[0][0]["kp"].shape[2]
+    bs = caches[0][0]["kp"].shape[-2]
     L = virt[0][0]["k"].shape[2]
     rows = start[:, None] + jnp.arange(width)[None, :]           # (B, W)
     take = jnp.minimum(rows, L - 1)[None, :, :, None, None]
@@ -494,16 +479,17 @@ def scatter_paged_back(caches, virt, bt, start, width: int, stop=None):
     if stop is not None:
         phys = jnp.where(rows < stop[:, None], phys, 0)
     off = rows % bs
+    # one pool_write per stacked layer: pools (R, N, KV, bs, hd), rows
+    # (R, B, W, KV, hd)
+    write = jax.vmap(lambda p, r: attn.pool_write(p, phys, off, r))
     out = []
     for gc, gv in zip(caches, virt):
         row_out = []
         for c, cv in zip(gc, gv):
             kr = jnp.take_along_axis(cv["k"], take, axis=2)
             vr = jnp.take_along_axis(cv["v"], take, axis=2)
-            row_out.append({
-                "kp": c["kp"].at[:, phys, off].set(kr.astype(c["kp"].dtype)),
-                "vp": c["vp"].at[:, phys, off].set(vr.astype(c["vp"].dtype)),
-            })
+            row_out.append({"kp": write(c["kp"], kr),
+                            "vp": write(c["vp"], vr)})
         out.append(row_out)
     return out
 

@@ -5,7 +5,7 @@ The dense engine pins one ``max_len`` KV block per decode slot, so slot
 This module is the host half of the paged alternative:
 
   * the device holds one **block pool** per attention layer —
-    ``(num_blocks, block_size, kv_heads, head_dim)`` for K and V — shared
+    ``(num_blocks, kv_heads, block_size, head_dim)`` for K and V — shared
     by every sequence on the engine;
   * a sequence owns a **block table**: the list of physical block ids
     backing its virtual positions ``[0, pos)``, allocated on demand as

@@ -64,11 +64,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.sharding import shard_map_compat
 from repro.optim.compression import quantize, compressed_psum, dequantize
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 G = jax.random.normal(jax.random.PRNGKey(0), (8, 512))   # per-worker grads
 
 def reduce_fn(g):
@@ -76,8 +75,8 @@ def reduce_fn(g):
     val, _ = compressed_psum(c, "data")
     return val[None] / 8.0
 
-fn = shard_map_compat(reduce_fn, mesh=mesh, in_specs=(P("data", None),),
-                      out_specs=P("data", None))
+fn = jax.shard_map(reduce_fn, mesh=mesh, in_specs=(P("data", None),),
+                   out_specs=P("data", None), check_vma=False)
 out = jax.jit(fn)(G)
 true = jnp.mean(G, axis=0)
 err = float(jnp.max(jnp.abs(out[0] - true)))

@@ -14,8 +14,8 @@ import sys, json
 import jax
 from repro.launch import dryrun_lib
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 4), ("data", "model"))
 arch, shape = sys.argv[1], sys.argv[2]
 res = dryrun_lib.run_cell(arch, shape, mesh)
 print("RESULT " + json.dumps(res.to_json()))

@@ -69,8 +69,8 @@ def test_paged_decode_attention(B, nb_seq, bs, H, KV, hd, dtype):
     k0 = jax.random.PRNGKey(13)
     num_blocks = B * nb_seq + 1                 # + reserved null block 0
     q = rand(jax.random.fold_in(k0, 0), (B, H, hd), dtype)
-    kp = rand(jax.random.fold_in(k0, 1), (num_blocks, bs, KV, hd), dtype)
-    vp = rand(jax.random.fold_in(k0, 2), (num_blocks, bs, KV, hd), dtype)
+    kp = rand(jax.random.fold_in(k0, 1), (num_blocks, KV, bs, hd), dtype)
+    vp = rand(jax.random.fold_in(k0, 2), (num_blocks, KV, bs, hd), dtype)
     # each sequence owns a random disjoint set of physical blocks, in a
     # scrambled order — exactly what a long-lived allocator produces
     perm = np.asarray(jax.random.permutation(jax.random.fold_in(k0, 3),
@@ -95,11 +95,11 @@ def test_paged_decode_matches_dense_decode():
     v = rand(jax.random.fold_in(k0, 2), (B, L, KV, hd), jnp.float32)
     lengths = jnp.asarray([L, 23])
     # scatter the dense caches into a pool, sequences interleaved
-    kp = jnp.concatenate([jnp.zeros((1, bs, KV, hd))] +
-                         [k[b, j * bs:(j + 1) * bs][None]
+    kp = jnp.concatenate([jnp.zeros((1, KV, bs, hd))] +
+                         [k[b, j * bs:(j + 1) * bs].swapaxes(0, 1)[None]
                           for j in range(nb) for b in range(B)])
-    vp = jnp.concatenate([jnp.zeros((1, bs, KV, hd))] +
-                         [v[b, j * bs:(j + 1) * bs][None]
+    vp = jnp.concatenate([jnp.zeros((1, KV, bs, hd))] +
+                         [v[b, j * bs:(j + 1) * bs].swapaxes(0, 1)[None]
                           for j in range(nb) for b in range(B)])
     bt = jnp.asarray([[1 + j * B + b for j in range(nb)]
                       for b in range(B)], jnp.int32)
@@ -122,8 +122,8 @@ def test_paged_extend_attention(B, S, nb_seq, bs, H, KV, hd, dtype):
     k0 = jax.random.PRNGKey(17)
     num_blocks = B * nb_seq + 1
     q = rand(jax.random.fold_in(k0, 0), (B, S, H, hd), dtype)
-    kp = rand(jax.random.fold_in(k0, 1), (num_blocks, bs, KV, hd), dtype)
-    vp = rand(jax.random.fold_in(k0, 2), (num_blocks, bs, KV, hd), dtype)
+    kp = rand(jax.random.fold_in(k0, 1), (num_blocks, KV, bs, hd), dtype)
+    vp = rand(jax.random.fold_in(k0, 2), (num_blocks, KV, bs, hd), dtype)
     perm = np.asarray(jax.random.permutation(jax.random.fold_in(k0, 3),
                                              num_blocks - 1)) + 1
     bt = jnp.asarray(perm.reshape(B, nb_seq), jnp.int32)
@@ -147,11 +147,11 @@ def test_paged_extend_matches_dense_flash_prefill():
     q = rand(jax.random.fold_in(k0, 0), (B, S, H, hd), jnp.float32)
     k = rand(jax.random.fold_in(k0, 1), (B, S, KV, hd), jnp.float32)
     v = rand(jax.random.fold_in(k0, 2), (B, S, KV, hd), jnp.float32)
-    kp = jnp.concatenate([jnp.zeros((1, bs, KV, hd))] +
-                         [k[b, j * bs:(j + 1) * bs][None]
+    kp = jnp.concatenate([jnp.zeros((1, KV, bs, hd))] +
+                         [k[b, j * bs:(j + 1) * bs].swapaxes(0, 1)[None]
                           for j in range(nb) for b in range(B)])
-    vp = jnp.concatenate([jnp.zeros((1, bs, KV, hd))] +
-                         [v[b, j * bs:(j + 1) * bs][None]
+    vp = jnp.concatenate([jnp.zeros((1, KV, bs, hd))] +
+                         [v[b, j * bs:(j + 1) * bs].swapaxes(0, 1)[None]
                           for j in range(nb) for b in range(B)])
     bt = jnp.asarray([[1 + j * B + b for j in range(nb)]
                       for b in range(B)], jnp.int32)
@@ -188,10 +188,12 @@ def test_pair_score(N, M, d, dtype):
 def test_ssm_scan(B, S, D, N, chunk):
     k0 = jax.random.PRNGKey(11)
     # realistic stable dynamics: a in (0,1), b small
-    a = jax.nn.sigmoid(rand(jax.random.fold_in(k0, 0), (B, S, D, N),
+    # the kernel's lane-dense (B, S, N, D) layout; the recurrence is
+    # elementwise, so the oracle takes any layout
+    a = jax.nn.sigmoid(rand(jax.random.fold_in(k0, 0), (B, S, N, D),
                             jnp.float32))
-    b = rand(jax.random.fold_in(k0, 1), (B, S, D, N), jnp.float32) * 0.1
-    h0 = rand(jax.random.fold_in(k0, 2), (B, D, N), jnp.float32)
+    b = rand(jax.random.fold_in(k0, 1), (B, S, N, D), jnp.float32) * 0.1
+    h0 = rand(jax.random.fold_in(k0, 2), (B, N, D), jnp.float32)
     from repro.kernels.ssm_scan import ssm_scan_blocked
     hs, hT = ssm_scan_blocked(a, b, h0, chunk=chunk, block_d=min(64, D),
                               interpret=True)

@@ -112,8 +112,8 @@ pcfg = PipelineConfig(feat_dim=256, claim_capacity=16, evid_capacity=32)
 models, _ = margot_models(pcfg)
 docs = synthetic_corpus(4, 32, seed=5)
 X, keys, _ = corpus_arrays(docs, dim=256)
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 step_sharded = make_batch_step(pcfg, mesh=mesh)
 out_s = step_sharded(models, jnp.asarray(X), jnp.asarray(keys))
 links_s = {(c, e) for c, e, _ in extract_links(out_s)}

@@ -82,7 +82,7 @@ def test_launch_train_driver_resumes(tmp_path):
 
 @pytest.mark.slow
 def test_launch_serve_driver():
-    r = _run("repro.launch.serve", "--requests", "3", "--max-new", "4",
-             "--slots", "2", "--max-len", "64")
+    r = _run("repro.launch.serve", "--reduced", "--requests", "3",
+             "--max-new", "4", "--slots", "2", "--max-len", "64")
     assert r.returncode == 0, r.stdout + r.stderr
     assert "tok/s=" in r.stdout
