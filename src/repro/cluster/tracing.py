@@ -12,7 +12,9 @@ Three pieces, all cheap enough to leave compiled in:
     tracers return a shared no-op span (one branch per call site);
     enabled tracers sample *per root* (``sample_rate``), and every child
     inherits the root's decision through its :class:`TraceContext`, so a
-    request is traced everywhere or nowhere.
+    request is traced everywhere or nowhere.  Spans opened with
+    ``step=True`` (the engine's step path) also enter the ``jax.profiler``
+    trace while it runs — see "Profiler" below.
   * :class:`TraceContext` — the four scalars that cross the process /
     socket boundary (trace id, parent span id, sampled flag, attempt
     number).  It rides as an optional trailing element on ``("req", ...)``
@@ -32,8 +34,19 @@ Three pieces, all cheap enough to leave compiled in:
 Exporters: :func:`to_chrome_trace` (Chrome trace-event JSON, loadable in
 Perfetto / ``chrome://tracing``, one track per replica and per stage) and
 :func:`prometheus_text` (text exposition of a merged registry snapshot).
-Opt-in ``jax.profiler`` hooks (:func:`start_profiling` /
-:func:`annotate`) put device time in the same timeline.
+
+Profiler: between :func:`start_profiling` and :func:`stop_profiling` every
+step span (``Tracer.span(..., step=True)``) is also a
+``jax.profiler.TraceAnnotation`` under its span name — whether or not a
+tracer is installed and whether or not the request was sampled — so the
+engine's span tree (``engine.step`` > ``engine.admit`` > ``engine.prefill``;
+``engine.decode_sync`` > ``engine.kv_prep``, ``engine.host_sync``,
+``engine.stream_emit``; ``engine.submit``) shares the device ops' clock.
+A step span records in the tracer's buffer only under a sampled parent: it
+never roots a trace of its own, so per-step spans cannot flood the buffer.
+With the profiler off a disabled tracer's ``span()`` costs one more flag
+check than before.  JAX is imported only by :func:`start_profiling`:
+spawned workers import this module without it.
 
 Leaf module: imports nothing from the cluster package except
 ``metrics`` (itself a leaf), so every layer — wire, transport, replica,
@@ -56,7 +69,6 @@ import re
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence
 
 from repro.cluster.metrics import HIST_BUCKET_BOUNDS
@@ -115,7 +127,7 @@ class Span:
     a plain dict in the tracer's buffer; after that it is inert."""
 
     __slots__ = ("_tracer", "trace_id", "span_id", "parent_id", "name",
-                 "tags", "_t0", "_done")
+                 "tags", "_t0", "_done", "_ann")
 
     def __init__(self, tracer: "Tracer", trace_id: str, span_id: str,
                  parent_id: Optional[str], name: str):
@@ -127,6 +139,7 @@ class Span:
         self.tags: Dict[str, Any] = {}
         self._t0 = time.monotonic()
         self._done = False
+        self._ann = None                # profiler annotation (step spans)
 
     @property
     def recording(self) -> bool:
@@ -149,6 +162,8 @@ class Span:
         if self._done:
             return
         self._done = True
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._tracer._record({
             "trace": self.trace_id, "span": self.span_id,
             "parent": self.parent_id, "name": self.name,
@@ -197,6 +212,25 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(_NullSpan):
+    """A step span the tracer does not record: only its profiler
+    annotation, open from creation to ``end()``."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _open_annotation(name)
+
+    def end(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def __exit__(self, etype, exc, tb) -> bool:
+        self.end()
+        return False
+
+
 class Tracer:
     """Thread-safe span factory over a bounded per-process buffer.
 
@@ -223,27 +257,38 @@ class Tracer:
     def _new_id(self) -> str:
         return f"{self._prefix}-{next(self._ids):x}"
 
-    def span(self, name: str, parent: Any = None, **tags) -> Any:
+    def span(self, name: str, parent: Any = None, *, step: bool = False,
+             **tags) -> Any:
         """Start a span.  ``parent`` may be None (root), a
-        :class:`TraceContext`, or another :class:`Span`."""
+        :class:`TraceContext`, or another :class:`Span`.
+
+        ``step=True`` marks a span of the engine's step path: while the
+        profiler runs it also enters the profiler trace under ``name``, and
+        it records here only under a sampled parent (never as a root)."""
         if not self.enabled:
-            return NULL_SPAN
+            return _ProfilerSpan(name) if _PROFILING and step else NULL_SPAN
         if parent is None:
-            if self.sample_rate < 1.0 and \
-                    self._rng.random() >= self.sample_rate:
-                return NULL_SPAN
-            sp = Span(self, self._new_id(), self._new_id(), None, name)
+            if step or (self.sample_rate < 1.0 and
+                        self._rng.random() >= self.sample_rate):
+                sp = None
+            else:
+                sp = Span(self, self._new_id(), self._new_id(), None, name)
         else:
             if isinstance(parent, (Span, _NullSpan)):
                 parent = parent.ctx
             if parent is None or not parent.sampled:
-                return NULL_SPAN
-            sp = Span(self, parent.trace_id, self._new_id(),
-                      parent.span_id, name)
-            if parent.attempt:
-                sp.tags["attempt"] = parent.attempt
+                sp = None
+            else:
+                sp = Span(self, parent.trace_id, self._new_id(),
+                          parent.span_id, name)
+                if parent.attempt:
+                    sp.tags["attempt"] = parent.attempt
+        if sp is None:
+            return _ProfilerSpan(name) if _PROFILING and step else NULL_SPAN
         if tags:
             sp.tag(**tags)
+        if _PROFILING and step:
+            sp._ann = _open_annotation(name)
         return sp
 
     # -- buffer ----------------------------------------------------------
@@ -520,17 +565,25 @@ def prometheus_text(snapshot: Dict[str, float],
 
 
 # ----------------------------------------------------------------------
-# Opt-in jax.profiler hooks: device time in the same timeline.
+# Opt-in jax.profiler hooks: step spans and device time on one clock.
 
 _PROFILING = False
+_ANNOTATION: Any = None         # jax.profiler.TraceAnnotation, once started
+
+
+def _open_annotation(name: str) -> Any:
+    ann = _ANNOTATION(name)
+    ann.__enter__()
+    return ann
 
 
 def start_profiling(log_dir: str) -> None:
-    """Start a ``jax.profiler`` trace into ``log_dir`` and arm
-    :func:`annotate` (until then it is a ``nullcontext``)."""
-    global _PROFILING
+    """Start a ``jax.profiler`` trace into ``log_dir``; until
+    :func:`stop_profiling`, step spans also enter it."""
+    global _PROFILING, _ANNOTATION
     import jax
     jax.profiler.start_trace(log_dir)
+    _ANNOTATION = jax.profiler.TraceAnnotation
     _PROFILING = True
 
 
@@ -541,13 +594,3 @@ def stop_profiling() -> None:
     _PROFILING = False
     import jax
     jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """``TraceAnnotation`` around a jitted call while profiling is active
-    (so host-side stage names land in the device timeline); otherwise a
-    free ``nullcontext`` — safe to leave on every hot path."""
-    if not _PROFILING:
-        return nullcontext()
-    from jax.profiler import TraceAnnotation
-    return TraceAnnotation(name)

@@ -239,8 +239,9 @@ def main(argv=None):
                          "text exposition format")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the run "
-                         "into DIR (TensorBoard/Perfetto loadable); adds "
-                         "TraceAnnotation markers around prefill/decode")
+                         "into DIR (TensorBoard/Perfetto loadable); the "
+                         "engine's step spans (engine.step, engine.admit, "
+                         "engine.decode_sync, ...) land in it too")
     ap.add_argument("--stats-port", type=int, default=None, metavar="PORT",
                     help="serve live stats over HTTP: /metrics (Prometheus), "
                          "/timeseries.json, /slo.json, /dash (HTML "

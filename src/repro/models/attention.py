@@ -394,10 +394,12 @@ def attn_decode(params, x, cache, pos, cfg, *, kind: str):
     L = cache["k"].shape[1]
     slot = (pos % L) if ring else pos                    # (B,)
     cache = dict(cache)
-    cache["k"] = batched_cache_update(cache["k"], k[:, 0], slot)
-    cache["v"] = batched_cache_update(cache["v"], v[:, 0], slot)
+    with jax.named_scope("kv_write"):
+        cache["k"] = batched_cache_update(cache["k"], k[:, 0], slot)
+        cache["v"] = batched_cache_update(cache["v"], v[:, 0], slot)
+        if ring:
+            cache["pos"] = batched_cache_update(cache["pos"], pos, slot)
     if ring:
-        cache["pos"] = batched_cache_update(cache["pos"], pos, slot)
         valid = (cache["pos"] >= 0) & (cache["pos"] > (pos[:, None] - cfg.window)) \
             & (cache["pos"] <= pos[:, None])
     else:
@@ -424,16 +426,18 @@ def attn_extend(params, x, cache, pos0, cfg, *, kind: str):
     L = cache["k"].shape[1]
     bidx = jnp.arange(B)[:, None]
     cache = dict(cache)
-    cache["k"] = cache["k"].at[bidx, positions].set(
-        k.astype(cache["k"].dtype), mode="drop")
-    cache["v"] = cache["v"].at[bidx, positions].set(
-        v.astype(cache["v"].dtype), mode="drop")
+    with jax.named_scope("kv_write"):
+        cache["k"] = cache["k"].at[bidx, positions].set(
+            k.astype(cache["k"].dtype), mode="drop")
+        cache["v"] = cache["v"].at[bidx, positions].set(
+            v.astype(cache["v"].dtype), mode="drop")
     valid = jnp.arange(L)[None, None, :] <= positions[:, :, None]
     out = mha(q, cache["k"], cache["v"], valid[:, None], cfg.attn_softcap)
     out = out.reshape(B, S, -1) @ params["wo"]
     return out, cache
 
 
+@jax.named_scope("kv_write")
 def prefill_into_cache(params_unused, k, v, cache, cfg, *, kind: str):
     """Write full-seq K/V (B,S,KV,hd) into a fresh cache."""
     S = k.shape[1]
@@ -499,6 +503,7 @@ def pool_write(pool, phys, off, rows):
     return pool.at[phys, :, off].set(rows.astype(pool.dtype))
 
 
+@jax.named_scope("kv_write")
 def _paged_scatter(cache, k, v, vpos, bt):
     """Write per-position K/V rows into the pool through the block table.
 

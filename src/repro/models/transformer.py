@@ -129,8 +129,24 @@ def init_paged_caches(cfg, num_blocks: int, block_size: int):
 # per-layer apply
 def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None):
     """Returns (x, aux, new_cache).  ``bt`` is the (B, nb) block table
-    when ``cache`` is paged (decode/extend modes)."""
-    aux = jnp.zeros((), jnp.float32)
+    when ``cache`` is paged (decode/extend modes).
+
+    The mixer half (``ln1`` through its residual add) runs under the
+    named scope ``attention`` (``recurrent`` for S/R layers), the FFN half
+    (``ln2`` through its residual add) under ``mlp``: each op's metadata
+    carries the scope, so a profiler trace can split a layer's device time
+    without the scope costing anything at run time."""
+    mixer = "recurrent" if kind in ("S", "R") else "attention"
+    with jax.named_scope(mixer):
+        x, cache = _apply_mixer(p, x, cfg, kind, mode, cache, pos, bt)
+    if kind == "S":
+        return x, jnp.zeros((), jnp.float32), cache
+    with jax.named_scope("mlp"):
+        return _apply_ffn(p, x, cfg, kind) + (cache,)
+
+
+def _apply_mixer(p, x, cfg, kind: str, mode: str, cache, pos, bt):
+    """``x + mixer(ln1(x))`` and the layer's new cache."""
     h = apply_norm(p["ln1"], x, cfg)
 
     if kind == "S":
@@ -140,7 +156,7 @@ def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None):
             mix, new_state = ssm_mod.ssm_forward(
                 p["mixer"], h, cfg, state=None)
             cache = new_state if mode == "prefill" else cache
-        return x + mix, aux, cache
+        return x + mix, cache
 
     if kind == "R":
         if mode == "decode":
@@ -198,16 +214,18 @@ def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None):
             mix = attn.attn_forward(p["mixer"], h, cfg, kind=akind, qkv=(q, k, v))
         else:
             mix = attn.attn_forward(p["mixer"], h, cfg, kind=akind)
-    x = x + mix
+    return x + mix, cache
 
+
+def _apply_ffn(p, x, cfg, kind: str):
+    """``(x + ffn(ln2(x)), aux)``: aux is the MoE router loss, else 0."""
+    aux = jnp.zeros((), jnp.float32)
     h2 = apply_norm(p["ln2"], x, cfg)
     if kind == "M":
         f, aux = moe_mod.apply_moe(p["ffn"], h2, cfg)
-    elif kind == "D":
-        f = apply_mlp(p["ffn"], h2, cfg)
     else:
         f = apply_mlp(p["ffn"], h2, cfg)
-    return x + f, aux, cache
+    return x + f, aux
 
 
 # ----------------------------------------------------------------------
@@ -311,27 +329,34 @@ def run_backbone(params, x, cfg, mode: str, caches=None, pos=None, bt=None):
 
 
 # ----------------------------------------------------------------------
-# public entry points
-def forward(params, cfg, tokens=None, embeds=None):
-    """Full-sequence causal LM forward.  Returns (logits, aux)."""
+# public entry points.  The token embedding runs under the named scope
+# ``embed``; the final norm, LM head and sampling under ``head``.
+@jax.named_scope("embed")
+def _embed(params, cfg, tokens=None, embeds=None):
     if embeds is None:
         x = embed(params["embedding"], tokens, cfg)
     else:
         x = embeds.astype(cfg.act_dtype)
-    x = shard(x, "batch", "seq", "embed")
+    return shard(x, "batch", "seq", "embed")
+
+
+@jax.named_scope("head")
+def _final(params, x, cfg):
+    """Final norm and LM head: logits."""
+    return _head(params, apply_norm(params["final_norm"], x, cfg), cfg)
+
+
+def forward(params, cfg, tokens=None, embeds=None):
+    """Full-sequence causal LM forward.  Returns (logits, aux)."""
+    x = _embed(params, cfg, tokens, embeds)
     x, aux, _ = run_backbone(params, x, cfg, "full")
-    x = apply_norm(params["final_norm"], x, cfg)
-    return _head(params, x, cfg), aux
+    return _final(params, x, cfg), aux
 
 
 def prefill(params, cfg, tokens, caches, embeds=None, last_index=None):
     """Fill caches with a full pass; returns (logits at `last_index`
     (default: final position), caches)."""
-    if embeds is None:
-        x = embed(params["embedding"], tokens, cfg)
-    else:
-        x = embeds.astype(cfg.act_dtype)
-    x = shard(x, "batch", "seq", "embed")
+    x = _embed(params, cfg, tokens, embeds)
     x, aux, caches = run_backbone(params, x, cfg, "prefill", caches,
                                   pos=None)
     if last_index is None:
@@ -346,21 +371,16 @@ def prefill(params, cfg, tokens, caches, embeds=None, last_index=None):
             # own index
             x = jnp.take_along_axis(x, li.astype(jnp.int32)[:, None, None],
                                     axis=1)
-    x = apply_norm(params["final_norm"], x, cfg)
-    logits = _head(params, x, cfg)
-    return logits, caches
+    return _final(params, x, cfg), caches
 
 
 def decode_step(params, cfg, tokens, caches, pos, bt=None):
     """tokens: (B,1) int32; pos: (B,) absolute position being written;
     ``bt``: (B, nb) block table when ``caches`` are paged."""
-    x = embed(params["embedding"], tokens, cfg)
-    x = shard(x, "batch", "seq", "embed")
+    x = _embed(params, cfg, tokens)
     x, aux, caches = run_backbone(params, x, cfg, "decode", caches, pos=pos,
                                   bt=bt)
-    x = apply_norm(params["final_norm"], x, cfg)
-    logits = _head(params, x, cfg)
-    return logits, caches
+    return _final(params, x, cfg), caches
 
 
 def extend_paged(params, cfg, tokens, caches, pos0, bt, last_index):
@@ -370,17 +390,15 @@ def extend_paged(params, cfg, tokens, caches, pos0, bt, last_index):
     and returning logits at per-row ``last_index`` (into the suffix) plus
     the updated pool caches.  With ``pos0 == 0`` this is a full paged
     prefill."""
-    x = embed(params["embedding"], tokens, cfg)
-    x = shard(x, "batch", "seq", "embed")
+    x = _embed(params, cfg, tokens)
     x, aux, caches = run_backbone(params, x, cfg, "extend", caches,
                                   pos=pos0, bt=bt)
     li = jnp.asarray(last_index).astype(jnp.int32)
     x = jnp.take_along_axis(x, li[:, None, None], axis=1)
-    x = apply_norm(params["final_norm"], x, cfg)
-    logits = _head(params, x, cfg)
-    return logits, caches
+    return _final(params, x, cfg), caches
 
 
+@jax.named_scope("head")
 def sample_tokens(logits, temperature: float = 0.0, rng=None):
     """In-jit sampling.  logits: (B, V) -> (B,) int32.
 
@@ -579,13 +597,10 @@ def verify_extend(params, cfg, tokens, caches, pos0):
     token for position ``pos0 + j + 1`` — provided tokens[0..j] all match
     what that loop would have emitted, which is precisely the accepted
     prefix the caller keeps."""
-    x = embed(params["embedding"], tokens, cfg)
-    x = shard(x, "batch", "seq", "embed")
+    x = _embed(params, cfg, tokens)
     x, _, caches = run_backbone(params, x, cfg, "extend", caches,
                                 pos=pos0, bt=None)
-    x = apply_norm(params["final_norm"], x, cfg)
-    logits = _head(params, x, cfg)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+    return sample_tokens(_final(params, x, cfg)), caches
 
 
 def spec_decode_loop(params, cfg, caches, hist, pos, last, active, remaining,

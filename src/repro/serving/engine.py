@@ -49,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.cluster.metrics import MetricsRegistry
-from repro.cluster.tracing import (NULL_SPAN, annotate, current_recorder,
+from repro.cluster.tracing import (NULL_SPAN, current_recorder,
                                    current_tracer)
 from repro.models import api, transformer as tfm
 from repro.serving.kvpool import (NULL_BLOCK, BlockAllocator, PoolExhausted,
@@ -265,8 +265,10 @@ class EngineFns:
         # batch-1 admits — so MoE admits stay batch-1
         self.row_coupled = any(k == "M" for g in cfg.groups
                                for k in g.pattern)
-        self.decode = jax.jit(
-            lambda p, t, c, pos: tfm.decode_step(p, cfg, t, c, pos))
+        def decode(params, tokens, caches, pos):
+            return tfm.decode_step(params, cfg, tokens, caches, pos)
+
+        self.decode = jax.jit(decode)
         # jit-cache builds are locked: the bundle is shared across thread
         # replicas, and a duplicated build means a duplicated multi-second
         # XLA compile — the exact cost this class exists to amortize
@@ -424,8 +426,8 @@ class EngineFns:
         bucket, n = key
         cfg, scfg = self.cfg, self.scfg
 
-        def fn(params, tokens, last_idx, slot_idx, budget,
-               caches, pos, last, active, remaining, rng):
+        def dense_admit(params, tokens, last_idx, slot_idx, budget,
+                        caches, pos, last, active, remaining, rng):
             """tokens (n,bucket) · last_idx/slot_idx/budget (n,) ·
             engine state donated; returns (first_tokens (n,), state...)."""
             small = api.init_caches(cfg, n, scfg.max_len)
@@ -453,7 +455,7 @@ class EngineFns:
             return toks, caches, pos, last, active, remaining, rng
 
         self._admit_cache[key] = jax.jit(
-            fn, donate_argnums=(5, 6, 7, 8, 9, 10))
+            dense_admit, donate_argnums=(5, 6, 7, 8, 9, 10))
         return self._admit_cache[key]
 
     def paged_admit_fn(self, bucket: int, n: int) -> Callable:
@@ -470,8 +472,8 @@ class EngineFns:
         cfg, scfg = self.cfg, self.scfg
         spec = self.spec
 
-        def fn(params, tokens, meta, bt, virt,
-               caches, hist, pos, last, active, remaining, rng):
+        def paged_admit(params, tokens, meta, bt, virt,
+                        caches, hist, pos, last, active, remaining, rng):
             """tokens (n,bucket) suffix ids · meta (4,n) = [pos0
             cached-prefix length; last_idx suffix-local last index;
             slot_idx; budget] packed into one upload · bt (n, nb_max)
@@ -519,7 +521,8 @@ class EngineFns:
         # hist/virt may arrive as None (non-speculative engines; no
         # resident view yet) — an empty pytree, so donating it is a no-op
         # and jit re-traces once per presence combination
-        jitted = jax.jit(fn, donate_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+        jitted = jax.jit(paged_admit,
+                         donate_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
         self._paged_admit_cache[key] = jitted
         return self._paged_admit_cache[key]
 
@@ -530,11 +533,11 @@ class EngineFns:
             if plen not in self.prefill_cache:
                 cfg, scfg = self.cfg, self.scfg
 
-                def fn(params, tokens):
+                def prefill_exact(params, tokens):
                     caches = api.init_caches(cfg, 1, scfg.max_len)
                     return tfm.prefill(params, cfg, tokens, caches)
 
-                self.prefill_cache[plen] = jax.jit(fn)
+                self.prefill_cache[plen] = jax.jit(prefill_exact)
             return self.prefill_cache[plen]
 
 
@@ -629,28 +632,30 @@ class Engine:
                trace_ctx: Any = None, priority: int = 0,
                deadline_s: Optional[float] = None,
                cancel_cb: Optional[Callable[[], bool]] = None) -> Request:
-        req = Request(rid=next(self._rids),
-                      prompt=np.asarray(prompt, np.int32), max_new=max_new,
-                      submit_t=time.perf_counter(), on_tokens=on_tokens,
-                      priority=priority, deadline_s=deadline_s,
-                      cancel_cb=cancel_cb)
-        if deadline_s is not None or cancel_cb is not None:
-            self._watch_early = True
-        if self.paged and self.scfg.prefix_cache:
-            # sha256 prefix-chain hashing runs here — off the admit/step
-            # critical path, and memoized across identical prompts
-            req.block_hashes = hash_token_blocks_memo(
-                req.prompt, self.scfg.block_size)
-        # with a cluster context this parents into the request's trace;
-        # standalone (trace_ctx None) it roots one, subject to sampling
-        sp = current_tracer().span("engine.request", parent=trace_ctx,
-                                   rid=req.rid, prompt_len=len(req.prompt),
-                                   max_new=max_new)
-        if sp.recording:
-            req.trace_span = sp
-            req.trace_ctx = sp.ctx
-        self.queue.append(req)
-        return req
+        with current_tracer().span("engine.submit", parent=trace_ctx,
+                                   step=True):
+            req = Request(rid=next(self._rids),
+                          prompt=np.asarray(prompt, np.int32), max_new=max_new,
+                          submit_t=time.perf_counter(), on_tokens=on_tokens,
+                          priority=priority, deadline_s=deadline_s,
+                          cancel_cb=cancel_cb)
+            if deadline_s is not None or cancel_cb is not None:
+                self._watch_early = True
+            if self.paged and self.scfg.prefix_cache:
+                # sha256 prefix-chain hashing runs here — off the admit/step
+                # critical path, and memoized across identical prompts
+                req.block_hashes = hash_token_blocks_memo(
+                    req.prompt, self.scfg.block_size)
+            # with a cluster context this parents into the request's trace;
+            # standalone (trace_ctx None) it roots one, subject to sampling
+            sp = current_tracer().span("engine.request", parent=trace_ctx,
+                                       rid=req.rid, prompt_len=len(req.prompt),
+                                       max_new=max_new)
+            if sp.recording:
+                req.trace_span = sp
+                req.trace_ctx = sp.ctx
+            self.queue.append(req)
+            return req
 
     def _emit(self, req: Request, toks: List[int], done: bool):
         """Per-sync streaming callback; a throwing consumer must not take
@@ -717,6 +722,8 @@ class Engine:
     def _admit_fused(self):
         free = [s for s in range(self.scfg.slots) if self.active[s] is None]
         while free and self.queue:
+            asp = current_tracer().span(
+                "engine.admit", parent=self.queue[0].trace_ctx, step=True)
             # longest same-bucket *prefix* of the queue (strict FIFO), up to
             # the number of free slots, prefilled as one padded batch
             bucket = self.fns.bucket(len(self.queue[0].prompt))
@@ -746,14 +753,10 @@ class Engine:
                 tokens[j, :plen] = req.prompt
                 last_idx[j] = plen - 1
                 budget[j] = max(req.max_new, 0)
-            asp = current_tracer().span(
-                "engine.admit",
-                parent=next((r.trace_ctx for r in batch
-                             if r.trace_ctx is not None), None),
-                bucket=bucket, n=n, n_pad=n_pad,
-                rids=[r.rid for r in batch])
-            current_recorder().record("admit", rids=[r.rid for r in batch],
-                                      bucket=bucket, n=n)
+            rids = [r.rid for r in batch]
+            if asp.recording:
+                asp.tag(bucket=bucket, n=n, n_pad=n_pad, rids=rids)
+            current_recorder().record("admit", rids=rids, bucket=bucket, n=n)
             _qh = self.metrics.histogram("engine.queue_wait_s")
             _now = time.perf_counter()
             for r in batch:
@@ -762,17 +765,16 @@ class Engine:
             # sync that realizes its tokens — tracing never reaches
             # inside jit, it measures the host-visible stage
             psp = current_tracer().span("engine.prefill", parent=asp,
-                                        bucket=bucket, n_pad=n_pad)
-            with annotate("prefill"):
-                toks, self.caches, self._pos, self._last, self._active, \
-                    self._remaining, self._rng = \
-                    self.fns.admit_fn(bucket, n_pad)(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(last_idx),
-                        jnp.asarray(row_slots), jnp.asarray(budget),
-                        self.caches, self._pos, self._last,
-                        self._active, self._remaining, self._rng)
-                toks_h = np.asarray(toks)[n_pad - n:]
+                                        step=True, bucket=bucket, n_pad=n_pad)
+            toks, self.caches, self._pos, self._last, self._active, \
+                self._remaining, self._rng = \
+                self.fns.admit_fn(bucket, n_pad)(
+                    self.params, jnp.asarray(tokens),
+                    jnp.asarray(last_idx),
+                    jnp.asarray(row_slots), jnp.asarray(budget),
+                    self.caches, self._pos, self._last,
+                    self._active, self._remaining, self._rng)
+            toks_h = np.asarray(toks)[n_pad - n:]
             psp.end()
             now = time.perf_counter()
             for j, req in enumerate(batch):
@@ -793,27 +795,33 @@ class Engine:
         return next((r.trace_ctx for r in self.active
                      if r is not None and r.trace_ctx is not None), None)
 
+    def _decode_sync_span(self):
+        dsp = current_tracer().span("engine.decode_sync",
+                                    parent=self._batch_ctx(), step=True)
+        if dsp.recording:
+            dsp.tag(k=self.scfg.sync_every,
+                    n_active=sum(r is not None for r in self.active))
+        return dsp
+
     def _step_fused(self) -> bool:
         self._admit_fused()
         if not any(r is not None for r in self.active):
             return False
-        dsp = current_tracer().span(
-            "engine.decode_sync", parent=self._batch_ctx(),
-            k=self.scfg.sync_every,
-            n_active=sum(r is not None for r in self.active))
-        with annotate("decode_loop"):
-            out, emitted, self.caches, self._pos, self._last, self._active, \
-                self._remaining, self._rng = self.fns.decode_loop(
-                    self.params, self.caches, self._pos, self._last,
-                    self._active, self._remaining, self._rng)
-            # one host sync per K decode steps (sampling happened in-jit)
-            hsp = current_tracer().span("engine.host_sync", parent=dsp)
-            out_h = np.asarray(out)
-            em_h = np.asarray(emitted)
-            act_h = np.asarray(self._active)
-            rem_h = np.asarray(self._remaining)
-            hsp.end()
-        esp = current_tracer().span("engine.stream_emit", parent=dsp) \
+        dsp = self._decode_sync_span()
+        out, emitted, self.caches, self._pos, self._last, self._active, \
+            self._remaining, self._rng = self.fns.decode_loop(
+                self.params, self.caches, self._pos, self._last,
+                self._active, self._remaining, self._rng)
+        # one host sync per K decode steps (sampling happened in-jit)
+        hsp = current_tracer().span("engine.host_sync", parent=dsp,
+                                    step=True)
+        out_h = np.asarray(out)
+        em_h = np.asarray(emitted)
+        act_h = np.asarray(self._active)
+        rem_h = np.asarray(self._remaining)
+        hsp.end()
+        esp = current_tracer().span("engine.stream_emit", parent=dsp,
+                                    step=True) \
             if any(r is not None and r.on_tokens is not None
                    for r in self.active) else NULL_SPAN
         for s, req in enumerate(self.active):
@@ -877,171 +885,176 @@ class Engine:
         self._emit(req, [], True)
 
     def _admit_paged(self):
-        scfg = self.scfg
-        free = [s for s in range(scfg.slots) if self.active[s] is None]
+        free = [s for s in range(self.scfg.slots) if self.active[s] is None]
         while free and self.queue:
-            # NO flush here, by construction: admission only reads
-            # *published* prefix blocks (immutable once published — decode
-            # writes COW first) and only binds *free* blocks, while every
-            # lazily-pending virtual row targets a live slot's private
-            # block (fork/victim flush before sharing or freeing, and
-            # _finish resets a dead slot's watermark) — so the pool is
-            # authoritative for everything an admit can touch
-            if self.queue[0].kv_snapshot is not None:
-                # a preempted session resumes by block import, never by
-                # re-prefill; deferring it keeps FIFO (nothing behind it
-                # may overtake the resume)
-                if self._try_restore(free):
-                    continue
-                self.metrics.counter("engine.admit_deferred_kv").inc()
-                break
+            # opened before the batch is formed, so the span covers the
+            # prefix lookups, block allocation and table building too
+            with current_tracer().span("engine.admit",
+                                       parent=self.queue[0].trace_ctx,
+                                       step=True) as asp:
+                if not self._admit_paged_batch(free, asp):
+                    break
+
+    def _admit_paged_batch(self, free: List[int], asp) -> bool:
+        """Admit one batch from the queue head into ``free`` (taken in
+        place); False when the head has to wait for pool headroom."""
+        scfg = self.scfg
+        # NO flush here, by construction: admission only reads
+        # *published* prefix blocks (immutable once published — decode
+        # writes COW first) and only binds *free* blocks, while every
+        # lazily-pending virtual row targets a live slot's private
+        # block (fork/victim flush before sharing or freeing, and
+        # _finish resets a dead slot's watermark) — so the pool is
+        # authoritative for everything an admit can touch
+        if self.queue[0].kv_snapshot is not None:
+            # a preempted session resumes by block import, never by
+            # re-prefill; deferring it keeps FIFO (nothing behind it
+            # may overtake the resume)
+            if self._try_restore(free):
+                return True
+            self.metrics.counter("engine.admit_deferred_kv").inc()
+            return False
+        try:
+            prep = self._prep_paged(self.queue[0])
+        except _PromptTooLong as e:
+            self._reject_oversized(self.queue.popleft(), str(e))
+            return True
+        if prep is None:
+            # pool pressure: leave the queue intact — admission
+            # headroom gating upstream keeps this rare
+            self.metrics.counter("engine.admit_deferred_kv").inc()
+            return False
+        bucket = self.fns.bucket(prep[3])
+        max_admit = 1 if self.fns.row_coupled else len(free)
+        # pop-and-commit one request at a time so each headroom probe
+        # sees the blocks its batch-mates already claimed
+        rows = []
+        while prep is not None and len(rows) < max_admit and \
+                self.fns.bucket(prep[3]) == bucket:
+            req = self.queue.popleft()
+            hashes, hits, n_cached_tok, suffix_len = prep
+            plen = len(req.prompt)
+            slot = free[len(rows)]
+            sid = self.alloc.new_seq()
+            self.alloc.append_shared(sid, hits)
+            self.alloc.extend_to(sid, plen)
+            self._seq_of_slot[slot] = sid
+            self._bt[slot] = padded_table(self.alloc.table(sid),
+                                          self.nb_max)
+            self._bt_dirty = True
+            self._pos_h[slot] = plen
+            self._wb_h[slot] = plen   # nothing pending: admit writes pool
+            self._rem_h[slot] = max(req.max_new, 0)
+            self._act_h[slot] = req.max_new > 0 and \
+                plen < scfg.max_len - 1
+            self.metrics.counter("engine.prefix_hit_blocks").inc(
+                len(hits))
+            # denominator of the hit rate: count the blocks actually
+            # *looked up* (reuse is capped at plen-1 tokens), not the
+            # prompt's full-block count — else a block-aligned prompt
+            # could never reach hit_rate 1.0
+            self.metrics.counter("engine.prefix_lookup_blocks").inc(
+                max(plen - 1, 0) // self.scfg.block_size)
+            self.metrics.counter("engine.prefill_tokens_saved").inc(
+                n_cached_tok)
+            rows.append((req, slot, sid, hashes, n_cached_tok,
+                         suffix_len))
             try:
-                prep = self._prep_paged(self.queue[0])
-            except _PromptTooLong as e:
-                self._reject_oversized(self.queue.popleft(), str(e))
-                continue
-            if prep is None:
-                # pool pressure: leave the queue intact — admission
-                # headroom gating upstream keeps this rare
-                self.metrics.counter("engine.admit_deferred_kv").inc()
-                break
-            bucket = self.fns.bucket(prep[3])
-            max_admit = 1 if self.fns.row_coupled else len(free)
-            # pop-and-commit one request at a time so each headroom probe
-            # sees the blocks its batch-mates already claimed
-            rows = []
-            while prep is not None and len(rows) < max_admit and \
-                    self.fns.bucket(prep[3]) == bucket:
-                req = self.queue.popleft()
-                hashes, hits, n_cached_tok, suffix_len = prep
-                plen = len(req.prompt)
-                slot = free[len(rows)]
-                sid = self.alloc.new_seq()
-                self.alloc.append_shared(sid, hits)
-                self.alloc.extend_to(sid, plen)
-                self._seq_of_slot[slot] = sid
-                self._bt[slot] = padded_table(self.alloc.table(sid),
-                                              self.nb_max)
-                self._bt_dirty = True
-                self._pos_h[slot] = plen
-                self._wb_h[slot] = plen   # nothing pending: admit writes pool
-                self._rem_h[slot] = max(req.max_new, 0)
-                self._act_h[slot] = req.max_new > 0 and \
-                    plen < scfg.max_len - 1
-                self.metrics.counter("engine.prefix_hit_blocks").inc(
-                    len(hits))
-                # denominator of the hit rate: count the blocks actually
-                # *looked up* (reuse is capped at plen-1 tokens), not the
-                # prompt's full-block count — else a block-aligned prompt
-                # could never reach hit_rate 1.0
-                self.metrics.counter("engine.prefix_lookup_blocks").inc(
-                    max(plen - 1, 0) // self.scfg.block_size)
-                self.metrics.counter("engine.prefill_tokens_saved").inc(
-                    n_cached_tok)
-                rows.append((req, slot, sid, hashes, n_cached_tok,
-                             suffix_len))
-                try:
-                    # a snapshot-carrying head never joins a prefill
-                    # batch — the outer loop restores it via block import
-                    prep = self._prep_paged(self.queue[0]) \
-                        if self.queue and \
-                        self.queue[0].kv_snapshot is None else None
-                except _PromptTooLong:
-                    # oversized next prompt: stop batching here; the head
-                    # of the next admit loop rejects it individually,
-                    # after this batch's extend has run
-                    prep = None
-            n = len(rows)
-            free = free[n:]
-            # pad the batch dim to a power of two (same compile-bounding
-            # trick as the dense admit); pad rows duplicate row 0 and its
-            # slot/table — identical values to identical addresses
-            n_pad = _next_pow2(n) if n > 1 else 1
-            full = [rows[0]] * (n_pad - n) + rows
-            tokens = np.zeros((n_pad, bucket), np.int32)
-            pos0 = np.zeros((n_pad,), np.int32)
-            last_idx = np.zeros((n_pad,), np.int32)
-            slot_arr = np.zeros((n_pad,), np.int32)
-            budget = np.zeros((n_pad,), np.int32)
-            bt = np.zeros((n_pad, self.nb_max), np.int32)
-            for j, (req, slot, sid, hashes, n_cached_tok, suffix_len) in \
-                    enumerate(full):
-                tokens[j, :suffix_len] = req.prompt[n_cached_tok:]
-                pos0[j] = n_cached_tok
-                last_idx[j] = suffix_len - 1
-                slot_arr[j] = slot
-                budget[j] = max(req.max_new, 0)
-                bt[j] = self._bt[slot]
-            hit_toks = sum(r[4] for r in rows)
-            asp = current_tracer().span(
-                "engine.admit",
-                parent=next((r[0].trace_ctx for r in rows
-                             if r[0].trace_ctx is not None), None),
-                bucket=bucket, n=n, n_pad=n_pad,
-                rids=[r[0].rid for r in rows],
-                prefix_hit_tokens=hit_toks,
-                kv_blocks_free=self.alloc.free_blocks)
-            current_recorder().record(
-                "admit", rids=[r[0].rid for r in rows], bucket=bucket,
-                n=n, prefix_hit_tokens=hit_toks)
-            _qh = self.metrics.histogram("engine.queue_wait_s")
-            _now = time.perf_counter()
-            for r in rows:
-                _qh.observe(_now - r[0].submit_t)
-            psp = current_tracer().span("engine.prefill", parent=asp,
-                                        bucket=bucket, n_pad=n_pad)
-            with annotate("prefill"):
-                # one packed (4, n_pad) upload for the per-row int vectors
-                # — host->device dispatches dominate the admit wall here.
-                # The jit also re-gathers the admitted slots' rows of the
-                # resident view in the same call (other slots' lazily-
-                # pending rows must NOT be re-read from the pool); a
-                # prompt wider than the resident view is fine — decode's
-                # width check (need > width) forces a flush + full
-                # regather before any truncated row could be read.
-                meta = np.stack([pos0, last_idx, slot_arr, budget])
-                toks, self._virt, self.caches, hist, self._pos, \
-                    self._last, self._active, self._remaining, self._rng = \
-                    self.fns.paged_admit_fn(bucket, n_pad)(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(meta), jnp.asarray(bt), self._virt,
-                        self.caches,
-                        self._hist if self.speculative else None,
-                        self._pos, self._last, self._active,
-                        self._remaining, self._rng)
-                if self.speculative:
-                    self._hist = hist
-                toks_h = np.asarray(toks)[n_pad - n:]
-            psp.end()
-            now = time.perf_counter()
-            for j, (req, slot, sid, hashes, n_cached_tok, suffix_len) in \
-                    enumerate(rows):
-                plen = len(req.prompt)
-                if self.speculative and n_cached_tok:
-                    # a prefix-cache hit skips the admit extend for the
-                    # cached tokens, so the in-jit history seeding never
-                    # sees them — backfill host-side (admits are rare;
-                    # this keeps the n-gram draft sighted over the whole
-                    # context instead of just the uncached suffix)
-                    self._hist = self._hist.at[slot, :n_cached_tok].set(
-                        jnp.asarray(req.prompt[:n_cached_tok], jnp.int32))
-                if scfg.prefix_cache:
-                    # every *full* prompt block is now written and
-                    # immutable (decode writes start at plen) — publish it
-                    n_full = plen // scfg.block_size
-                    self.alloc.prefix_insert(hashes[:n_full],
-                                             self.alloc.table(sid)[:n_full])
-                req.out_tokens.append(int(toks_h[j]))
-                req.first_token_t = now
-                self.active[slot] = req
-                if req.max_new <= 0:
-                    self._finish(slot, "max_new")
-                elif plen >= scfg.max_len - 1:
-                    self._finish(slot, "max_len")
-                self._emit(req, req.out_tokens[-1:], req.done)
-            asp.end()
-            self.metrics.counter("engine.prefill_batches").inc()
-            self._kv_gauges()
+                # a snapshot-carrying head never joins a prefill
+                # batch — the outer loop restores it via block import
+                prep = self._prep_paged(self.queue[0]) \
+                    if self.queue and \
+                    self.queue[0].kv_snapshot is None else None
+            except _PromptTooLong:
+                # oversized next prompt: stop batching here; the head
+                # of the next admit loop rejects it individually,
+                # after this batch's extend has run
+                prep = None
+        n = len(rows)
+        del free[:n]
+        # pad the batch dim to a power of two (same compile-bounding
+        # trick as the dense admit); pad rows duplicate row 0 and its
+        # slot/table — identical values to identical addresses
+        n_pad = _next_pow2(n) if n > 1 else 1
+        full = [rows[0]] * (n_pad - n) + rows
+        tokens = np.zeros((n_pad, bucket), np.int32)
+        pos0 = np.zeros((n_pad,), np.int32)
+        last_idx = np.zeros((n_pad,), np.int32)
+        slot_arr = np.zeros((n_pad,), np.int32)
+        budget = np.zeros((n_pad,), np.int32)
+        bt = np.zeros((n_pad, self.nb_max), np.int32)
+        for j, (req, slot, sid, hashes, n_cached_tok, suffix_len) in \
+                enumerate(full):
+            tokens[j, :suffix_len] = req.prompt[n_cached_tok:]
+            pos0[j] = n_cached_tok
+            last_idx[j] = suffix_len - 1
+            slot_arr[j] = slot
+            budget[j] = max(req.max_new, 0)
+            bt[j] = self._bt[slot]
+        hit_toks = sum(r[4] for r in rows)
+        rids = [r[0].rid for r in rows]
+        if asp.recording:
+            asp.tag(bucket=bucket, n=n, n_pad=n_pad, rids=rids,
+                    prefix_hit_tokens=hit_toks,
+                    kv_blocks_free=self.alloc.free_blocks)
+        current_recorder().record("admit", rids=rids, bucket=bucket, n=n,
+                                  prefix_hit_tokens=hit_toks)
+        _qh = self.metrics.histogram("engine.queue_wait_s")
+        _now = time.perf_counter()
+        for r in rows:
+            _qh.observe(_now - r[0].submit_t)
+        psp = current_tracer().span("engine.prefill", parent=asp,
+                                    step=True, bucket=bucket, n_pad=n_pad)
+        # one packed (4, n_pad) upload for the per-row int vectors —
+        # host->device dispatches dominate the admit wall here.  The jit
+        # also re-gathers the admitted slots' rows of the resident view
+        # in the same call (other slots' lazily-pending rows must NOT be
+        # re-read from the pool); a prompt wider than the resident view
+        # is fine — decode's width check (need > width) forces a flush +
+        # full regather before any truncated row could be read.
+        meta = np.stack([pos0, last_idx, slot_arr, budget])
+        toks, self._virt, self.caches, hist, self._pos, \
+            self._last, self._active, self._remaining, self._rng = \
+            self.fns.paged_admit_fn(bucket, n_pad)(
+                self.params, jnp.asarray(tokens),
+                jnp.asarray(meta), jnp.asarray(bt), self._virt,
+                self.caches,
+                self._hist if self.speculative else None,
+                self._pos, self._last, self._active,
+                self._remaining, self._rng)
+        if self.speculative:
+            self._hist = hist
+        toks_h = np.asarray(toks)[n_pad - n:]
+        psp.end()
+        now = time.perf_counter()
+        for j, (req, slot, sid, hashes, n_cached_tok, suffix_len) in \
+                enumerate(rows):
+            plen = len(req.prompt)
+            if self.speculative and n_cached_tok:
+                # a prefix-cache hit skips the admit extend for the
+                # cached tokens, so the in-jit history seeding never
+                # sees them — backfill host-side (admits are rare;
+                # this keeps the n-gram draft sighted over the whole
+                # context instead of just the uncached suffix)
+                self._hist = self._hist.at[slot, :n_cached_tok].set(
+                    jnp.asarray(req.prompt[:n_cached_tok], jnp.int32))
+            if scfg.prefix_cache:
+                # every *full* prompt block is now written and
+                # immutable (decode writes start at plen) — publish it
+                n_full = plen // scfg.block_size
+                self.alloc.prefix_insert(hashes[:n_full],
+                                         self.alloc.table(sid)[:n_full])
+            req.out_tokens.append(int(toks_h[j]))
+            req.first_token_t = now
+            self.active[slot] = req
+            if req.max_new <= 0:
+                self._finish(slot, "max_new")
+            elif plen >= scfg.max_len - 1:
+                self._finish(slot, "max_len")
+            self._emit(req, req.out_tokens[-1:], req.done)
+        self.metrics.counter("engine.prefill_batches").inc()
+        self._kv_gauges()
+        return True
 
     def _flush_virt(self):
         """Lazy-writeback flush: scatter every virtual-cache row decoded
@@ -1447,10 +1460,10 @@ class Engine:
         scfg = self.scfg
         d = scfg.spec_draft if self.speculative else 0
         adv = scfg.sync_every * (d + 1)   # max emissions in one sync
-        dsp = current_tracer().span(
-            "engine.decode_sync", parent=self._batch_ctx(),
-            k=scfg.sync_every,
-            n_active=sum(r is not None for r in self.active))
+        dsp = self._decode_sync_span()
+        # allocate-ahead, COW and the block-table upload (or the resident
+        # view's regather) before the loop is dispatched
+        ksp = current_tracer().span("engine.kv_prep", parent=dsp, step=True)
         if scfg.kv_swap:
             # swap preflight: make room by preempting whole sessions
             # BEFORE any table mutates below, so swap-outs export
@@ -1488,6 +1501,7 @@ class Engine:
                 self._bt_dirty = True
             max_hi = max(max_hi, hi)
         if not any(r is not None for r in self.active):
+            ksp.end()
             dsp.end()
             return True
         if cow_src:
@@ -1548,48 +1562,48 @@ class Engine:
                 self._bt_dev = jnp.asarray(self._bt[:, :nbw])
                 self._bt_width = nbw
                 self._bt_dirty = False
-        with annotate("decode_loop"):
-            if self.speculative:
-                ssp = current_tracer().span("engine.spec_decode",
-                                            parent=dsp, draft_len=d)
-                packed, self._virt, self._hist, self._pos, self._last, \
-                    self._active, self._remaining, self._rng = \
-                    self.fns.spec_decode_loop(
-                        self.params, self._virt, self._hist, self._pos,
-                        self._last, self._active, self._remaining,
-                        self._rng)
-            elif use_virt:
-                packed, self._virt, self._pos, self._last, self._active, \
-                    self._remaining, self._rng = self.fns.paged_decode_loop(
-                        self.params, self._virt, self._pos, self._last,
-                        self._active, self._remaining, self._rng)
-            else:
-                packed, self._bt_dev, self.caches, self._pos, self._last, \
-                    self._active, self._remaining, self._rng = \
-                    self.fns.paged_decode_loop(
-                        self.params, self._bt_dev, self.caches, self._pos,
-                        self._last, self._active, self._remaining,
-                        self._rng)
-            hsp = current_tracer().span("engine.host_sync", parent=dsp)
-            # ONE device fetch: [tokens | emitted]; liveness, positions and
-            # budgets advance host-side by exactly the emitted counts
-            packed_h = np.asarray(packed)
-            if self.speculative:
-                out_h, em_h = packed_h[:, :-3], packed_h[:, -3]
-            else:
-                out_h, em_h = packed_h[:, :-1], packed_h[:, -1]
-            self._pos_h += em_h.astype(np.int64)
-            self._rem_h -= em_h.astype(np.int64)
-            self._act_h &= (self._rem_h > 0) & \
-                (self._pos_h < scfg.max_len - 1)
-            if self.speculative:
-                acc, prop = int(packed_h[0, -2]), int(packed_h[0, -1])
-                self.metrics.counter("engine.spec_proposed").inc(prop)
-                self.metrics.counter("engine.spec_accepted").inc(acc)
-                ssp.tag(proposed=prop, accepted=acc)
-                ssp.end()
-            hsp.end()
-        esp = current_tracer().span("engine.stream_emit", parent=dsp) \
+        ksp.end()
+        if self.speculative:
+            ssp = current_tracer().span("engine.spec_decode", parent=dsp,
+                                        step=True, draft_len=d)
+            packed, self._virt, self._hist, self._pos, self._last, \
+                self._active, self._remaining, self._rng = \
+                self.fns.spec_decode_loop(
+                    self.params, self._virt, self._hist, self._pos,
+                    self._last, self._active, self._remaining, self._rng)
+        elif use_virt:
+            packed, self._virt, self._pos, self._last, self._active, \
+                self._remaining, self._rng = self.fns.paged_decode_loop(
+                    self.params, self._virt, self._pos, self._last,
+                    self._active, self._remaining, self._rng)
+        else:
+            packed, self._bt_dev, self.caches, self._pos, self._last, \
+                self._active, self._remaining, self._rng = \
+                self.fns.paged_decode_loop(
+                    self.params, self._bt_dev, self.caches, self._pos,
+                    self._last, self._active, self._remaining, self._rng)
+        hsp = current_tracer().span("engine.host_sync", parent=dsp,
+                                    step=True)
+        # ONE device fetch: [tokens | emitted]; liveness, positions and
+        # budgets advance host-side by exactly the emitted counts
+        packed_h = np.asarray(packed)
+        if self.speculative:
+            out_h, em_h = packed_h[:, :-3], packed_h[:, -3]
+        else:
+            out_h, em_h = packed_h[:, :-1], packed_h[:, -1]
+        self._pos_h += em_h.astype(np.int64)
+        self._rem_h -= em_h.astype(np.int64)
+        self._act_h &= (self._rem_h > 0) & \
+            (self._pos_h < scfg.max_len - 1)
+        if self.speculative:
+            acc, prop = int(packed_h[0, -2]), int(packed_h[0, -1])
+            self.metrics.counter("engine.spec_proposed").inc(prop)
+            self.metrics.counter("engine.spec_accepted").inc(acc)
+            ssp.tag(proposed=prop, accepted=acc)
+            ssp.end()
+        hsp.end()
+        esp = current_tracer().span("engine.stream_emit", parent=dsp,
+                                    step=True) \
             if any(r is not None and r.on_tokens is not None
                    for r in self.active) else NULL_SPAN
         for s, req in enumerate(self.active):
@@ -1715,13 +1729,14 @@ class Engine:
         """One engine iteration: admit, then decode — a single step on the
         reference path, ``sync_every`` fused steps (one host sync) on the
         fused and paged paths."""
-        if self._watch_early:
-            self._sweep_expired()
-        if self.paged:
-            return self._step_paged()
-        if self.scfg.fused:
-            return self._step_fused()
-        return self._step_reference()
+        with current_tracer().span("engine.step", step=True):
+            if self._watch_early:
+                self._sweep_expired()
+            if self.paged:
+                return self._step_paged()
+            if self.scfg.fused:
+                return self._step_fused()
+            return self._step_reference()
 
     def run_until_drained(self, max_steps: int = 10_000):
         steps = 0
