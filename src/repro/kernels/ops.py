@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.decode_attention import decode_attention_bhd
 from repro.kernels.paged_attention import (paged_decode_attention_bkgd,
-                                           paged_extend_attention_bkgd)
+                                           paged_extend_attention_bkgd,
+                                           paged_kv_write_bkgd)
 from repro.kernels.pair_score import pair_score_blocked
 from repro.kernels.ssm_scan import ssm_scan_blocked
 
@@ -52,21 +53,41 @@ def decode_attention(q, k, v, lengths, *, n_splits: int = 8,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           interpret: bool = False):
-    """q: (B,H,hd); k_pool/v_pool: (num_blocks, KV, bs, hd) shared pools;
-    block_tables: (B, nb); lengths: (B,) -> (B,H,hd).
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
+                           layer=None, *, interpret: bool = False):
+    """q: (B,H,hd); k_pool/v_pool: (num_blocks, KV, bs, hd) shared pools,
+    or (R, num_blocks, KV, bs, hd) stacked over layers with ``layer`` the
+    one to read; block_tables: (B, nb); lengths: (B,) -> (B,H,hd).
 
     The kernel gathers K/V through the block table inside the grid (scalar
-    prefetch resolves physical pool rows), so no dense per-sequence cache
-    is ever materialized."""
+    prefetch resolves the layer and the physical pool rows), so no dense
+    per-sequence cache, and no per-layer slice of a stack, is ever
+    materialized."""
     B, H, hd = q.shape
-    KV = k_pool.shape[1]
+    if k_pool.ndim == 4:        # a per-layer pool: layer 0 of a stack of one
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    KV = k_pool.shape[2]
     G = H // KV
     out = paged_decode_attention_bkgd(q.reshape(B, KV, G, hd),
                                       k_pool, v_pool, block_tables, lengths,
-                                      interpret=interpret)
+                                      layer, interpret=interpret)
     return out.reshape(B, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write(k_pool, v_pool, layer, phys, off, k_rows, v_rows, *,
+                   interpret: bool = False):
+    """``pool[layer, phys[b], :, off[b]] = rows[b]`` for both pools, in
+    place.  k_pool/v_pool: (R, num_blocks, KV, bs, hd); layer: scalar;
+    phys/off: (B,); k_rows/v_rows: (B, KV, hd) -> (k_pool, v_pool).
+
+    Each real block may be named by at most one row of a call; rows that
+    name the null block 0 leave junk there."""
+    return paged_kv_write_bkgd(k_pool, v_pool,
+                               jnp.asarray(layer, jnp.int32).reshape(1),
+                               phys, off, k_rows, v_rows,
+                               interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

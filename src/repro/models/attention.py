@@ -468,8 +468,9 @@ def prefill_into_cache(params_unused, k, v, cache, cfg, *, kind: str):
 # masked/pad writes are redirected into it, so a stale entry can corrupt
 # nothing.  Gather-through-the-table + masked mha is the exact jnp path
 # (and the parity oracle); ``cfg.use_kernels`` routes decode through the
-# Pallas paged kernel, which resolves pool rows via scalar-prefetched
-# block tables and never materializes a dense per-sequence cache.
+# Pallas paged kernels, which resolve the layer and the pool rows via
+# scalar-prefetched block tables, write and read the layer-stacked pools in
+# place, and never materialize a dense per-sequence cache.
 
 def init_paged_kv_cache(cfg, num_blocks: int, block_size: int):
     """Per-layer block pool ``(num_blocks + 1, KV, block_size, hd)``:
@@ -503,19 +504,22 @@ def pool_write(pool, phys, off, rows):
     return pool.at[phys, :, off].set(rows.astype(pool.dtype))
 
 
+def _pool_slots(vpos, bt, bs: int):
+    """Physical block and offset of each virtual position ``vpos (B, S)``
+    through the block table ``bt (B, nb)``.  Positions beyond the table
+    (prompt pads past ``nb*bs``) redirect to the null block."""
+    nb = bt.shape[1]
+    vblock = vpos // bs
+    phys = jnp.take_along_axis(bt, jnp.minimum(vblock, nb - 1), axis=1)
+    return jnp.where(vblock < nb, phys, 0), vpos % bs
+
+
 @jax.named_scope("kv_write")
 def _paged_scatter(cache, k, v, vpos, bt):
     """Write per-position K/V rows into the pool through the block table.
 
-    k/v: (B, S, KV, hd); vpos: (B, S) virtual positions; bt: (B, nb).
-    Positions beyond the table (prompt pads past ``nb*bs``) redirect to
-    the null block."""
-    bs = cache["kp"].shape[-2]
-    nb = bt.shape[1]
-    vblock = vpos // bs
-    phys = jnp.take_along_axis(bt, jnp.minimum(vblock, nb - 1), axis=1)
-    phys = jnp.where(vblock < nb, phys, 0)
-    off = vpos % bs
+    k/v: (B, S, KV, hd); vpos: (B, S) virtual positions; bt: (B, nb)."""
+    phys, off = _pool_slots(vpos, bt, cache["kp"].shape[-2])
     cache = dict(cache)
     cache["kp"] = pool_write(cache["kp"], phys, off, k)
     cache["vp"] = pool_write(cache["vp"], phys, off, v)
@@ -527,24 +531,40 @@ def _paged_gather(cache, bt):
     return pool_rows(cache["kp"], bt), pool_rows(cache["vp"], bt)
 
 
-def paged_attn_decode(params, x, cache, pos, bt, cfg, *, kind: str):
+def paged_attn_decode(params, x, cache, pos, bt, cfg, *, kind: str,
+                      layer=None):
     """Single decode step over a paged cache.
 
     x: (B,1,d); pos: (B,) absolute write position; bt: (B, nb) block
     table.  Same math as :func:`attn_decode` on a dense cache holding the
-    same tokens — validity is ``index <= pos`` either way."""
+    same tokens — validity is ``index <= pos`` either way.
+
+    On the kernel path (``cfg.use_kernels``) the cache holds the pools
+    stacked over the group's layers, ``(R, N, KV, bs, hd)``, as the layer
+    loop carries them, and ``layer`` names this layer: the new row goes in
+    through the aliased write kernel and the decode kernel reads the layer
+    from the stack, so neither slices nor copies a pool.  Each live row
+    writes a block no other row of the step writes (the engine allocates
+    and copies-on-write ahead of the loop); idle rows write the null
+    block."""
     B = x.shape[0]
     rope_base = cfg.rope_local_base if kind == "local" else cfg.rope_base
     q, k, v = _project_qkv(params, x, x, cfg, pos[:, None], pos[:, None],
                            rope_base)
-    cache = _paged_scatter(cache, k, v, pos[:, None], bt)
     if cfg.use_kernels:
         from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(q[:, 0], cache["kp"], cache["vp"],
-                                          bt, pos + 1,
-                                          interpret=kops.use_interpret())
+        interpret = kops.use_interpret()
+        phys, off = _pool_slots(pos[:, None], bt, cache["kp"].shape[-2])
+        with jax.named_scope("kv_write"):
+            kp, vp = kops.paged_kv_write(cache["kp"], cache["vp"], layer,
+                                         phys[:, 0], off[:, 0], k[:, 0],
+                                         v[:, 0], interpret=interpret)
+        cache = {"kp": kp, "vp": vp}
+        out = kops.paged_decode_attention(q[:, 0], kp, vp, bt, pos + 1,
+                                          layer, interpret=interpret)
         out = out[:, None]
     else:
+        cache = _paged_scatter(cache, k, v, pos[:, None], bt)
         kg, vg = _paged_gather(cache, bt)
         L = kg.shape[1]
         valid = jnp.arange(L)[None, :] <= pos[:, None]

@@ -108,8 +108,10 @@ def paged_supported(cfg, max_len: int) -> bool:
 def init_paged_caches(cfg, num_blocks: int, block_size: int):
     """Block-pool caches: one shared ``(num_blocks+1, KV, bs, hd)`` K/V
     pool per layer (row 0 reserved as the null block) instead of a dense
-    per-slot stripe.  Layout mirrors :func:`init_caches` so the scan
-    machinery is unchanged."""
+    per-slot stripe, stacked over each group's repeats like
+    :func:`init_caches`.  The admit scan slices a layer's pool from the
+    stack as ``xs``; the kernel decode loop carries the stacks whole
+    (:func:`run_backbone`)."""
     if not paged_supported(cfg, max_len=1 << 30):
         raise ValueError(f"{cfg.name}: family holds non-pageable state "
                          f"(SSM/RG-LRU/MLA/ring) — use the dense cache")
@@ -127,9 +129,11 @@ def init_paged_caches(cfg, num_blocks: int, block_size: int):
 
 # ----------------------------------------------------------------------
 # per-layer apply
-def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None):
+def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None,
+                layer=None):
     """Returns (x, aux, new_cache).  ``bt`` is the (B, nb) block table
-    when ``cache`` is paged (decode/extend modes).
+    when ``cache`` is paged (decode/extend modes); ``layer`` is the layer's
+    index in the stacked pools of ``cache`` on the kernel decode path.
 
     The mixer half (``ln1`` through its residual add) runs under the
     named scope ``attention`` (``recurrent`` for S/R layers), the FFN half
@@ -138,14 +142,15 @@ def apply_layer(p, x, cfg, kind: str, mode: str, cache, pos, bt=None):
     without the scope costing anything at run time."""
     mixer = "recurrent" if kind in ("S", "R") else "attention"
     with jax.named_scope(mixer):
-        x, cache = _apply_mixer(p, x, cfg, kind, mode, cache, pos, bt)
+        x, cache = _apply_mixer(p, x, cfg, kind, mode, cache, pos, bt,
+                                layer)
     if kind == "S":
         return x, jnp.zeros((), jnp.float32), cache
     with jax.named_scope("mlp"):
         return _apply_ffn(p, x, cfg, kind) + (cache,)
 
 
-def _apply_mixer(p, x, cfg, kind: str, mode: str, cache, pos, bt):
+def _apply_mixer(p, x, cfg, kind: str, mode: str, cache, pos, bt, layer):
     """``x + mixer(ln1(x))`` and the layer's new cache."""
     h = apply_norm(p["ln1"], x, cfg)
 
@@ -185,7 +190,8 @@ def _apply_mixer(p, x, cfg, kind: str, mode: str, cache, pos, bt):
                         and S % ctx.mesh.shape.get("model", 1) == 0)
         if mode == "decode" and attn.is_paged_cache(cache):
             mix, cache = attn.paged_attn_decode(p["mixer"], h, cache, pos,
-                                                bt, cfg, kind=akind)
+                                                bt, cfg, kind=akind,
+                                                layer=layer)
         elif mode == "extend" and attn.is_paged_cache(cache):
             # paged suffix prefill: S tokens appended at absolute position
             # `pos` (per row), attending through the block table
@@ -289,13 +295,57 @@ def _remat_wrap(fn, cfg):
     return jax.checkpoint(fn)
 
 
+def _carries_pools(cfg, mode: str, caches) -> bool:
+    """Kernel decode over paged pools: the layer loop carries the stacked
+    pools and the kernels write and read them in place."""
+    return (mode == "decode" and cfg.use_kernels and caches is not None
+            and all(attn.is_paged_cache(c) for gc in caches for c in gc))
+
+
+def _run_group_carrying_pools(gp, x, aux, cfg, g, pools, pos, bt):
+    """One group's layers in decode over its stacked paged pools
+    ``(R, N+1, KV, bs, hd)``, one stack per pattern position.
+
+    Only the parameters are scanned in per layer; the pools ride in the
+    carry whole, and each layer's write kernel (aliased) and decode kernel
+    take the layer index.  So no per-layer pool slice is taken or stacked
+    back, and the compiler keeps every pool in place from loop entry to
+    exit.  Always a scan without remat: the unrolled form serves the
+    dry-run's cost pass, which runs dense caches, and decode keeps nothing
+    for a backward pass."""
+    def body(carry, layer_ps):
+        r, xx, aux, pools = carry
+        pools = list(pools)
+        for pi, kind in enumerate(g.pattern):
+            xx, a, pools[pi] = apply_layer(layer_ps[pi], xx, cfg, kind,
+                                           "decode", pools[pi], pos, bt,
+                                           layer=r)
+            aux = aux + a
+        return (r + 1, xx, aux, pools), None
+
+    (_, x, aux, pools), _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.int32), x, aux, list(pools)), gp)
+    return x, aux, pools
+
+
 def run_backbone(params, x, cfg, mode: str, caches=None, pos=None, bt=None):
     """x: (B,S,d) embedded input.  Returns (x, aux, new_caches).
-    ``bt``: (B, nb) block table for paged caches (loop-invariant)."""
+    ``bt``: (B, nb) block table for paged caches (loop-invariant).
+
+    Kernel decode over paged pools carries the stacked pools through the
+    layer loop (:func:`_run_group_carrying_pools`); every other mode and
+    cache scans each layer's cache in as ``xs`` and stacks it back as
+    ``ys``."""
     aux0 = jnp.zeros((), jnp.float32)
     new_caches = []
+    carry_pools = _carries_pools(cfg, mode, caches)
     for gi, g in enumerate(cfg.groups):
         gp = params["groups"][gi]
+        if carry_pools:
+            x, aux0, pools = _run_group_carrying_pools(
+                gp, x, aux0, cfg, g, caches[gi], pos, bt)
+            new_caches.append(pools)
+            continue
         gc = caches[gi] if caches is not None else [None] * len(g.pattern)
 
         def body(carry, per_rep, _pattern=g.pattern):
@@ -529,9 +579,11 @@ def decode_loop(params, cfg, caches, pos, last, active, remaining, rng, *,
 
     With ``bt`` (paged caches) the jnp path runs gather-hoisted: virtual
     dense caches once per K steps, the identical dense body inside, one
-    bounded scatter-back at the end.  ``cfg.use_kernels`` keeps the
-    per-step pool path (the Pallas decode kernel reads the pool directly
-    and would gain nothing from a materialized dense copy).
+    bounded scatter-back at the end.  ``cfg.use_kernels`` runs every step
+    on the pools themselves, in place: the layer loop carries the stacked
+    pools, the write kernel puts each step's K/V row into its block and
+    the decode kernel reads the layer's blocks (:func:`run_backbone`), so
+    neither a dense copy nor a per-layer pool slice is materialized.
     """
     if bt is not None and not cfg.use_kernels:
         start = pos

@@ -57,11 +57,14 @@ def test_decode_attention(B, L, H, KV, hd, n_splits, dtype):
                                want.astype(jnp.float32), **TOL[dtype])
 
 
-@pytest.mark.parametrize("B,nb_seq,bs,H,KV,hd", [
+PAGED_DECODE_SHAPES = [
     (2, 4, 16, 8, 2, 64),    # GQA 4:1
     (1, 3, 32, 4, 4, 128),   # MHA
     (3, 5, 8, 4, 1, 64),     # MQA, small blocks
-])
+]
+
+
+@pytest.mark.parametrize("B,nb_seq,bs,H,KV,hd", PAGED_DECODE_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention(B, nb_seq, bs, H, KV, hd, dtype):
     """Kernel gathers K/V through a shuffled block table; must match the
@@ -106,6 +109,70 @@ def test_paged_decode_matches_dense_decode():
     out = ops.paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
     want = ref.decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,nb_seq,bs,H,KV,hd", PAGED_DECODE_SHAPES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_on_stacked_pools_reads_the_layer(B, nb_seq, bs, H, KV,
+                                                      hd, dtype):
+    """The decode kernel over pools stacked over layers, told layer l,
+    equals the per-layer call on ``pool[l]`` — bit for bit: the same
+    blocks come in and the math is the same."""
+    k0 = jax.random.PRNGKey(29)
+    R, num_blocks = 3, B * nb_seq + 1
+    q = rand(jax.random.fold_in(k0, 0), (B, H, hd), dtype)
+    kp = rand(jax.random.fold_in(k0, 1), (R, num_blocks, KV, bs, hd), dtype)
+    vp = rand(jax.random.fold_in(k0, 2), (R, num_blocks, KV, bs, hd), dtype)
+    perm = np.asarray(jax.random.permutation(jax.random.fold_in(k0, 3),
+                                             num_blocks - 1)) + 1
+    bt = jnp.asarray(perm.reshape(B, nb_seq), jnp.int32)
+    lengths = jax.random.randint(jax.random.fold_in(k0, 4), (B,), 1,
+                                 nb_seq * bs + 1)
+    for layer in range(R):
+        out = ops.paged_decode_attention(q, kp, vp, bt, lengths,
+                                         jnp.int32(layer), interpret=True)
+        want = ops.paged_decode_attention(q, kp[layer], vp[layer], bt,
+                                          lengths, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def _kv_write_case(k0, R, N, KV, bs, hd, phys, dtype):
+    B = len(phys)
+    kp = rand(jax.random.fold_in(k0, 0), (R, N, KV, bs, hd), dtype)
+    vp = rand(jax.random.fold_in(k0, 1), (R, N, KV, bs, hd), dtype)
+    kr = rand(jax.random.fold_in(k0, 2), (B, KV, hd), dtype)
+    vr = rand(jax.random.fold_in(k0, 3), (B, KV, hd), dtype)
+    off = jax.random.randint(jax.random.fold_in(k0, 4), (B,), 0, bs)
+    return kp, vp, jnp.asarray(phys, jnp.int32), off, kr, vr
+
+
+@pytest.mark.parametrize("phys", [
+    [3, 7, 1, 5, 2, 6],          # shuffled real blocks, one row each
+    [0, 4, 0, 0, 2, 0],          # idle rows share the null block
+])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kv_write_matches_scatter(phys, layer, dtype):
+    """The aliased block-write kernel stores exactly what
+    ``pool.at[layer, phys, :, off].set(rows)`` stores in every real block,
+    and leaves every other layer of the stack untouched.  The null block
+    takes junk when rows share it, so it is left out of the comparison."""
+    R, N, KV, bs, hd = 3, 8, 2, 16, 128
+    kp, vp, ph, off, kr, vr = _kv_write_case(jax.random.PRNGKey(31), R, N,
+                                             KV, bs, hd, phys, dtype)
+    want_k = kp.at[layer, ph, :, off].set(kr)
+    want_v = vp.at[layer, ph, :, off].set(vr)
+    got_k, got_v = ops.paged_kv_write(kp, vp, layer, ph, off, kr, vr,
+                                      interpret=True)
+    for got, want, pool in ((got_k, want_k, kp), (got_v, want_v, vp)):
+        got, want, pool = (np.asarray(a, np.float32)
+                           for a in (got, want, pool))
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        others = [r for r in range(R) if r != layer]
+        np.testing.assert_array_equal(got[others], pool[others])
+        if 0 not in phys:
+            np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("B,S,nb_seq,bs,H,KV,hd", [

@@ -13,7 +13,7 @@ import pytest
 from tests._hyp_compat import given, settings, st
 
 from repro.configs import get_config
-from repro.configs.base import reduced
+from repro.configs.base import ScanGroup, reduced
 from repro.models import api, transformer as tfm
 from repro.serving import BlockAllocator, Engine, PoolExhausted, ServeConfig
 from repro.serving.kvpool import hash_token_blocks
@@ -100,6 +100,73 @@ def test_paged_kernel_path_matches_reference_path():
                     prompts, max_new=5)
     for a, b in zip(ref, ker):
         assert a.out_tokens == b.out_tokens
+
+
+def _block_writers(bt, pos, active, remaining, k, max_len, bs):
+    """Every physical block the K-step loop writes, mapped to the slots
+    that write it: live slots at each position they decode, frozen and
+    idle slots at their frozen position, as the device loop does."""
+    writers = {}
+    nb = bt.shape[1]
+    pos, active, remaining = pos.copy(), active.copy(), remaining.copy()
+    for _ in range(k):
+        for s in range(len(pos)):
+            vb = pos[s] // bs
+            phys = int(bt[s, vb]) if vb < nb else 0
+            writers.setdefault(phys, set()).add(s)
+        pos = pos + active
+        remaining = remaining - active
+        active = active & (remaining > 0) & (pos < max_len - 1)
+    return writers
+
+
+@pytest.mark.parametrize("groups", [None, (ScanGroup(("A", "A"), 2),)],
+                         ids=["one_layer", "two_positions_two_repeats"])
+def test_paged_kernel_path_refills_slots_and_reuses_blocks(groups):
+    """More requests than slots on the kernel path, over a pool too small
+    to keep them all: slots refill, freed blocks are written again, and
+    the tokens equal the jnp path's — also where the decode loop carries a
+    stack of several layers for each of several pattern positions.  Before
+    every decode loop, no real block is written by two slots: the aliased
+    write kernel is exact only for blocks one row of a step names."""
+    cfg = reduced(get_config("internlm2-1.8b"))
+    if groups is not None:
+        cfg = cfg.replace(groups=groups,
+                          n_layers=sum(g.n_layers for g in groups))
+    params, _ = api.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(10)
+    prompts = [rng.randint(0, cfg.vocab, size=n).astype(np.int32)
+               for n in (9, 12, 5, 14, 7)]
+    scfg = ServeConfig(max_len=32, slots=2, fused=True, sync_every=4,
+                       paged=True, block_size=8, kv_blocks=7)
+    _, ref = _drain(params, cfg, scfg, prompts, max_new=6)
+
+    eng = Engine(params, cfg.replace(use_kernels=True), scfg)
+    loop = eng.fns.paged_decode_loop
+    shared, owners = [], {}
+
+    def checked_loop(params, bt, caches, pos, last, active, remaining, rng):
+        bt_h = np.asarray(bt)
+        writers = _block_writers(bt_h, np.asarray(pos), np.asarray(active),
+                                 np.asarray(remaining), scfg.sync_every,
+                                 scfg.max_len, scfg.block_size)
+        shared.extend(b for b, slots in writers.items()
+                      if b != 0 and len(slots) > 1)
+        for s, req in enumerate(eng.active):
+            if req is not None:
+                for b in set(bt_h[s].tolist()) - {0}:
+                    owners.setdefault(b, set()).add(id(req))
+        return loop(params, bt, caches, pos, last, active, remaining, rng)
+
+    eng.fns.paged_decode_loop = checked_loop
+    ker = [eng.submit(p, max_new=6) for p in prompts]
+    eng.run_until_drained()
+    assert not shared
+    # some block served one request, was freed, and served another
+    assert any(len(reqs) > 1 for reqs in owners.values())
+    for a, b in zip(ref, ker):
+        assert a.out_tokens == b.out_tokens
+        assert b.finish_reason == "max_new"
 
 
 def test_unpageable_family_falls_back_dense():

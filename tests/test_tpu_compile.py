@@ -9,6 +9,7 @@ compiles: an entry written here could not be read back without a chip.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.base import ScanGroup
 from repro.kernels import ops
+from repro.models import api, transformer as tfm
 
 INTERNLM2 = get_config("internlm2-1.8b")
 FALCON_MAMBA = get_config("falcon-mamba-7b")
@@ -56,12 +59,85 @@ def _compile(fn, one_chip, *shapes):
 B, BS, NB = 8, 16, 64
 H, KV, HD = INTERNLM2.n_heads, INTERNLM2.n_kv_heads, INTERNLM2.head_dim
 POOL = ((B * NB + 1, KV, BS, HD), jnp.bfloat16)
+# two layers' pools stacked, as the decode loop carries them
+STACK = ((2,) + POOL[0], jnp.bfloat16)
 
 
 def test_paged_decode_compiles(one_chip):
     _compile(functools.partial(ops.paged_decode_attention, interpret=False),
              one_chip, ((B, H, HD), jnp.bfloat16), POOL, POOL,
              ((B, NB), jnp.int32), ((B,), jnp.int32))
+
+
+def test_paged_decode_on_stacked_pools_compiles(one_chip):
+    _compile(functools.partial(ops.paged_decode_attention, interpret=False),
+             one_chip, ((B, H, HD), jnp.bfloat16), STACK, STACK,
+             ((B, NB), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
+
+
+def test_paged_kv_write_compiles(one_chip):
+    compiled = _compile(functools.partial(ops.paged_kv_write,
+                                          interpret=False),
+                        one_chip, STACK, STACK, ((), jnp.int32),
+                        ((B,), jnp.int32), ((B,), jnp.int32),
+                        ((B, KV, HD), jnp.bfloat16),
+                        ((B, KV, HD), jnp.bfloat16))
+    # the pools are updated in place: both outputs alias their inputs
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# ops that only name a buffer: they move no pool data
+_NAMING_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def test_paged_decode_loop_keeps_the_pool_in_place(one_chip, monkeypatch):
+    """The K-step paged decode loop on the kernel path, internlm2 widths at
+    two layers and 64 slots: no op but a Pallas kernel outputs a buffer of
+    a layer's or the stack's pool shape (no layout copy, slice, update
+    slice, scatter or fusion of a pool), and the temps hold less than one
+    layer's K pool.  The pool is large enough that the compiler keeps it
+    in HBM rather than staging it through on-chip memory."""
+    # kernels compile for the described chip, not the CPU's interpreter
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    layers, slots, blocks = 2, 64, 1024
+    cfg = INTERNLM2.replace(n_layers=layers,
+                            groups=(ScanGroup(("A",), layers),),
+                            use_kernels=True)
+    params, _ = api.abstract_params(cfg)
+    caches = jax.eval_shape(lambda: tfm.init_paged_caches(cfg, blocks, BS))
+
+    def loop(params, bt, caches, pos, last, active, remaining, rng):
+        return tfm.decode_loop(params, cfg, caches, pos, last, active,
+                               remaining, rng, k=8, max_len=NB * BS, bt=bt)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    row = lambda dtype: jax.ShapeDtypeStruct((slots,), dtype,
+                                             sharding=one_chip)
+    compiled = jax.jit(loop, donate_argnums=(2,)).lower(
+        on_chip(params),
+        jax.ShapeDtypeStruct((slots, NB), jnp.int32, sharding=one_chip),
+        on_chip(caches), row(jnp.int32), row(jnp.int32), row(jnp.bool_),
+        row(jnp.int32), on_chip(jax.random.PRNGKey(0))).compile()
+    # a layer's pool (N, KV, bs, hd), alone or with a leading layer axis
+    pool = re.compile(r"bf16\[(\d+,)?%d,%d,%d,%d\]" % ((blocks + 1, KV, BS,
+                                                         HD)))
+    movers = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\(", line)
+        if not m or not pool.search(m.group(2)):
+            continue
+        name, _, opcode = m.groups()
+        if opcode in _NAMING_OPS or 'custom_call_target="tpu_custom_call"' \
+                in line:
+            continue
+        movers.append(f"{name} ({opcode})")
+    assert not movers, movers
+    layer_pool_bytes = (blocks + 1) * KV * BS * HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
 
 
 @pytest.mark.parametrize("S", [32, 256])
