@@ -29,7 +29,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
-    from bench import check, reference
+    from bench import check
     from bench.registry import Registry
     reg = Registry()
     cell = reg.workload(args.workload)
@@ -42,8 +42,8 @@ def main(argv=None) -> int:
         prompts, outputs, failed = sess.served(client, seed)
         del client
         sess.free()
-        weights = reference.make_weights(sess.spec, seed)
-        v = check.compare(weights, sess.spec, prompts, outputs,
+        weights = sess.block.make_weights(sess.spec, seed)
+        v = check.compare(sess.block, weights, sess.spec, prompts, outputs,
                           sess.conf["check"]["max_gap"], failed, control=True)
         del weights
         print(json.dumps({"seed": seed, "failed": failed,

@@ -3,7 +3,7 @@
 Once the window has closed and every request in it has been followed to its
 end, a sample drawn from the seed of the requests it finished, the one with
 the most served tokens among them, is run through the float32 reference
-(``reference.served_gaps``), teacher-forced on the served tokens.  Compared:
+(the block's ``served_gaps``), teacher-forced on the served tokens.  Compared:
 
 * ``max_gap``: the widest gap, over every served token of the sample, by
   which the reference's logit of the served token lies below the
@@ -21,8 +21,6 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench.reference import served_gaps
-
 SAMPLE = 8
 
 
@@ -39,10 +37,11 @@ def sample(finished: Sequence[int], n_served: Dict[int, int], seed: int,
     return sorted([longest] + [rest[j] for j in pick])
 
 
-def compare(weights, spec, prompts, outputs, limit: float,
+def compare(block, weights, spec, prompts, outputs, limit: float,
             failed: int, control: bool = False) -> dict:
-    """The numbers compared, each beside its limit, and the verdict."""
-    res = served_gaps(weights, spec, prompts, outputs, control=control)
+    """The numbers compared, each beside its limit, and the verdict;
+    ``block`` is the configuration's block module."""
+    res = block.served_gaps(weights, spec, prompts, outputs, control=control)
     gaps, ctl = res if control else (res, None)
     worst = float(max(g.max() for g in gaps)) if gaps else float("nan")
     out = {

@@ -1,8 +1,8 @@
 """Model FLOPs the tokens decoded in the traced window require, over the
 device time of the decode-loop programs in the trace times the chip's peak
-bf16 FLOP/s, in %.  A decoded token at context c requires
-2 x (layer matmul parameters + head) + 4 x layers x heads x head_dim x c."""
-from bench import work
+bf16 FLOP/s, in %.  What a token decoded at context c requires is the
+cell's block's ``decode_flops`` (for a dense block, 2 x (layer matmul
+parameters + head) + attention over c positions)."""
 
 # the engine's jitted K-step decode loop on the paged kernel path, as the
 # trace's XLA Modules line names it
@@ -15,5 +15,6 @@ def read(ctx):
     busy = ctx.module_seconds(PROGRAM)
     if not busy:
         return None
-    flops = sum(work.decode_flops(ctx.spec, c) for c in ctx.decode_ctx)
+    flops = sum(ctx.block.decode_flops(ctx.spec, c, ctx.counters)
+                for c in ctx.decode_ctx)
     return 100.0 * flops / (busy * ctx.peak["bf16_flops_per_s"])

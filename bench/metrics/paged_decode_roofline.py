@@ -1,7 +1,8 @@
 """The paged-decode kernel's share of its roofline, in %: the least time
 the work the decoded tokens require could take (their live-context K and V
 plus q and o against HBM bandwidth, or their attention FLOPs against peak,
-whichever is larger) over the summed device time of the kernel's events."""
+whichever is larger; the cell's block's ``decode_attn``) over the summed
+device time of the kernel's events."""
 from bench import work
 
 # the Pallas kernel's custom call (kernels/paged_attention.py), named in the
@@ -17,5 +18,6 @@ def read(ctx):
         return None
     least = 0.0
     for c in ctx.decode_ctx:
-        least += work.least_seconds(*work.decode_attn(ctx.spec, c), ctx.peak)
+        least += work.least_seconds(
+            *ctx.block.decode_attn(ctx.spec, c, ctx.counters), ctx.peak)
     return 100.0 * least / busy

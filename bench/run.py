@@ -82,14 +82,24 @@ def pct(xs, q):
 
 
 class TraceCtx:
-    """What a per-layer metric's reader is given (see ``bench/metrics``)."""
+    """What a per-layer metric's reader is given (see ``bench/metrics``):
+    the trace (None where the window was not traced), the cell's block
+    module and its spec (work counts: ``block.decode_flops(spec, c,
+    counters)``), the chip's peaks, the context of each token decoded while
+    the trace ran, the engine's queue-wait p95 and count, every request's
+    time to first token, and what the engine counted while the trace ran
+    (``Server.counters()`` at the trace's stop less at its start), so that
+    the counts cover the same steps as ``decode_ctx``."""
 
-    def __init__(self, spec, peak, tr, decode_ctx, queue_wait, ttft_s=()):
+    def __init__(self, tr, *, block=None, spec=None, peak=None,
+                 decode_ctx=(), queue_wait=(0.0, 0), ttft_s=(),
+                 counters=None):
         from bench import trace as T
-        self.spec, self.peak, self.trace = spec, peak, tr
-        self.decode_ctx = decode_ctx
+        self.block, self.spec, self.peak, self.trace = block, spec, peak, tr
+        self.decode_ctx = list(decode_ctx)
         self.queue_wait = queue_wait
         self.ttft_s = list(ttft_s)      # every request of the window
+        self.counters = dict(counters or {})
         self._T = T
         if tr is not None:
             self.lo, self.hi = tr.window()
@@ -171,6 +181,14 @@ def dump_requests(client, path: str) -> None:
                    "requests": rows}, f)
 
 
+def traced_counts(before=None, after=None) -> dict:
+    """What the engine counted between two ``Server.counters()``
+    snapshots; empty unless both were taken."""
+    if before is None or after is None:
+        return {}
+    return {k: v - before.get(k, 0) for k, v in after.items()}
+
+
 class Session:
     """One process's set-up for a cell: weights from the seed, the engine
     warmed on every shape the mix reaches.  ``window()`` runs the measured
@@ -179,12 +197,15 @@ class Session:
 
     def __init__(self, reg, cell: dict, seed: int):
         import numpy as np
-        from bench import driver, reference, traffic
+        from bench import driver, traffic
         from bench import serving_adapter as sa
         self.conf = reg.config(cell["config"])
         self.mix = reg.traffic(cell["traffic"])
-        self.spec = reference.Spec.from_config(self.conf)
-        self.cfg = sa.arch(self.spec, self.conf["name"])
+        self.block = reg.block(self.conf)
+        self.spec = self.block.spec(self.conf)
+        self.vocab = self.block.vocab(self.spec)
+        self.cfg = sa.arch(self.block.program_fields(self.spec,
+                                                     self.conf["name"]))
         self.max_len = traffic.max_total(self.mix)
         self.counter = driver.CompileCounter()
         self.server = self.fns = None
@@ -192,7 +213,7 @@ class Session:
         self._engine(seed)
         t1 = time.perf_counter()
         rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 1])
-        n = driver.warm_up(self.server, self.mix, rng, self.spec.vocab)
+        n = driver.warm_up(self.server, self.mix, rng, self.vocab)
         self.fns = self.server.engine.fns
         log(f"set-up: imports and device {t0 - T_START:.3f} s, weights and "
             f"engine {t1 - t0:.3f} s, warm-up {time.perf_counter() - t1:.3f}"
@@ -202,16 +223,15 @@ class Session:
     def _engine(self, seed: Optional[int]) -> None:
         """A fresh engine over the compiled programs: on this seed's
         weights, or (``seed`` None) on the weights it has."""
-        from bench import reference
         from bench import serving_adapter as sa
         if seed is None:
             self.server = self.server.fresh()
             return
         self.free()
-        weights = reference.make_weights(self.spec, seed)
+        weights = self.block.make_weights(self.spec, seed)
         deploy = self.conf["deployment"]
-        self.server = sa.Server(sa.program_params(weights, self.cfg),
-                                self.cfg, slots=deploy["slots"],
+        params = self.block.program_params(weights, self.cfg.padded_vocab)
+        self.server = sa.Server(params, self.cfg, slots=deploy["slots"],
                                 max_len=self.max_len,
                                 pool_tokens=deploy["kv_pool_tokens"],
                                 shared=self.fns)
@@ -222,7 +242,7 @@ class Session:
         ``new_weights`` serves this seed's weights, made anew."""
         from bench import driver, traffic
         self._engine(seed if new_weights else None)
-        reqs = traffic.generate(self.mix, seed, seconds, self.spec.vocab)
+        reqs = traffic.generate(self.mix, seed, seconds, self.vocab)
         client = driver.Client(self.server, reqs, seconds)
         client.run(DRAIN_S, self.counter, trace)
         return client
@@ -268,16 +288,17 @@ def main(argv=None, reg=None, device=None) -> int:
         device = device_info(int(cell["chips"]))
 
     import jax
-    from bench import check, reference, work
+    from bench import check, work
     from bench import serving_adapter as sa
     from bench import trace as T
 
     sess = Session(reg, cell, args.seed)
-    trace_dir, holder, trace = None, [], None
+    trace_dir, holder, counts, trace = None, [], [], None
     if args.trace:
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
 
         def start():
+            counts.append(sess.server.counters())
             sa.start_trace(trace_dir)
             holder.append(jax.profiler.TraceAnnotation(T.WINDOW_SPAN))
             holder[-1].__enter__()
@@ -285,6 +306,7 @@ def main(argv=None, reg=None, device=None) -> int:
         def stop():
             holder[-1].__exit__(None, None, None)
             sa.stop_trace()
+            counts.append(sess.server.counters())
 
         trace = (TRACE_AT * args.seconds,
                  min(TRACE_S, 0.3 * args.seconds), start, stop)
@@ -299,9 +321,12 @@ def main(argv=None, reg=None, device=None) -> int:
     if args.dump_requests:
         dump_requests(client, args.dump_requests)
     late = client.lateness
-    log(f"KV pool: at most {client.peak_blocks} of {sess.server.pool_blocks}"
-        f" blocks ({100.0 * client.peak_blocks / sess.server.pool_blocks:.1f}"
-        f"%) reserved for the requests in flight at their full length")
+    pool, bs = sess.server.pool_blocks, sess.server.scfg.block_size
+    kv_b = sess.block.kv_bytes_per_token(sess.spec)
+    log(f"KV pool: {pool} blocks of {bs} tokens, {kv_b} B a token, "
+        f"{pool * bs * kv_b / 1e9:.3f} GB; at most {client.peak_blocks} "
+        f"blocks ({100.0 * client.peak_blocks / pool:.1f}%) reserved for the "
+        f"requests in flight at their full length")
     log(f"window {args.seconds} s: {len(attempted)} requests scheduled, "
         f"{failed} failed; generator lateness p50 {pct(late, 50)} s p95 "
         f"{pct(late, 95)} s max {max(late) if late else None} s")
@@ -322,8 +347,10 @@ def main(argv=None, reg=None, device=None) -> int:
         if args.dump_trace and tr is not None:
             dump_trace(tr, args.dump_trace)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = TraceCtx(sess.spec, work.peaks(device["kind"]), tr,
-                       client.decode_ctx, qw, ttft)
+        ctx = TraceCtx(tr, block=sess.block, spec=sess.spec,
+                       peak=work.peaks(device["kind"]),
+                       decode_ctx=client.decode_ctx, queue_wait=qw,
+                       ttft_s=ttft, counters=traced_counts(*counts))
         if tr is not None:
             result["device"]["busy_s"] = ctx.busy_s()
             result["device"]["window_s"] = ctx.window_s
@@ -347,9 +374,9 @@ def main(argv=None, reg=None, device=None) -> int:
     prompts, outputs, failed = sess.served(client, args.seed)
     del client
     sess.free()
-    weights = reference.make_weights(sess.spec, args.seed)
-    verdict = check.compare(weights, sess.spec, prompts, outputs,
-                            sess.conf["check"]["max_gap"], failed)
+    weights = sess.block.make_weights(sess.spec, args.seed)
+    verdict = check.compare(sess.block, weights, sess.spec, prompts,
+                            outputs, sess.conf["check"]["max_gap"], failed)
     result["correct"] = verdict["correct"]
     result["checks"] = verdict["checks"]
     log(f"compared {verdict['served_tokens_compared']} served tokens of "

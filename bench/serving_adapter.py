@@ -17,8 +17,10 @@ What it sets, and what it leaves to the program:
   ``prefill_bucketing``, ``min_bucket``, ``prefix_cache``.  A change of a
   default is measured on its new value.
 
-The architecture is built from the configuration file's numbers, not from
-the program's own preset, so the program runs as the configuration states.
+The architecture is built from the configuration file's numbers through
+its block's ``program_fields`` and ``program_params``
+(``bench/blocks/``), not from the program's own preset, so the program runs
+as the configuration states.
 """
 from __future__ import annotations
 
@@ -27,7 +29,6 @@ import os
 import sys
 from typing import Callable, Dict, List
 
-import jax
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
@@ -36,7 +37,7 @@ if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
 from repro.cluster import tracing as _tracing  # noqa: E402
-from repro.cluster.metrics import MetricsRegistry  # noqa: E402
+from repro.cluster.metrics import MetricsRegistry, is_gauge_key  # noqa: E402
 from repro.configs.base import ArchConfig, ScanGroup  # noqa: E402
 from repro.serving import Engine, ServeConfig  # noqa: E402
 
@@ -50,34 +51,14 @@ def block_size() -> int:
     return ServeConfig().block_size
 
 
-def arch(spec, name: str) -> ArchConfig:
-    """The program's architecture config for a ``reference.Spec``."""
-    kw = dict(name=name, family="dense", n_layers=spec.layers,
-              d_model=spec.d_model, n_heads=spec.heads,
-              n_kv_heads=spec.kv_heads, head_dim=spec.head_dim,
-              d_ff=spec.d_ff, vocab=spec.vocab,
-              groups=(ScanGroup(("A",), spec.layers),),
-              rope_base=spec.rope_theta, mlp="swiglu", norm="rmsnorm",
-              norm_eps=spec.eps, tie_embeddings=False, dtype=spec.dtype,
-              param_dtype=spec.dtype)
+def arch(fields: dict) -> ArchConfig:
+    """The program's architecture config from a block's ``program_fields``,
+    whose ``groups`` are ``(pattern, repeats)`` pairs."""
+    kw = dict(fields, groups=tuple(ScanGroup(tuple(p), int(r))
+                                   for p, r in fields["groups"]))
     if "use_kernels" in _fields(ArchConfig):
         kw["use_kernels"] = True
     return ArchConfig(**kw)
-
-
-def program_params(w, cfg: ArchConfig):
-    """The program's parameter tree, holding the benchmark's weights."""
-    L = w["layers"]
-    layer = {"ln1": {"w": L["ln1_w"]}, "ln2": {"w": L["ln2_w"]},
-             "ffn": {k: L[k] for k in ("w_gate", "w_up", "w_down")},
-             "mixer": {k: L[k] for k in ("wq", "wk", "wv", "wo")}}
-    table, head = w["embed"], w["head"]
-    pad = cfg.padded_vocab - table.shape[0]
-    if pad:
-        table = jax.numpy.pad(table, ((0, pad), (0, 0)))
-        head = jax.numpy.pad(head, ((0, 0), (0, pad)))
-    return {"embedding": {"table": table}, "groups": [[layer]],
-            "final_norm": {"w": w["final_w"]}, "lm_head": head}
 
 
 TokenCallback = Callable[[int, List[int], bool], None]
@@ -159,13 +140,23 @@ class Server:
         h = self.metrics.histogram("engine.queue_wait_s")
         return h.percentile(95), h.count
 
+    def counters(self) -> dict:
+        """The engine's monotone counts as a flat dict of numbers: its
+        counters by name and each histogram's ``<name>.count`` and bucket
+        counts, without levels (gauges, means, percentiles), as the
+        program's ``is_gauge_key`` tells them apart.  The difference of two
+        snapshots is what the engine counted between them."""
+        return {k: v for k, v in self.metrics.snapshot().items()
+                if not is_gauge_key(k)}
+
     def close(self) -> None:
         self.engine = None
 
 
 def start_trace(log_dir: str) -> None:
     """Start the profiler through the program's hook, which also arms its
-    host spans (``prefill``, ``decode_loop``) in the same trace."""
+    step spans (``engine.step``, ``engine.admit``, ``engine.prefill``,
+    ``engine.decode_sync`` and the rest) in the same trace."""
     _tracing.start_profiling(log_dir)
 
 

@@ -11,6 +11,7 @@ from bench_tiny import TINY, tiny_config
 from bench import check, driver
 from bench import reference as R
 from bench import traffic as TR
+from bench.blocks import dense_gqa as G
 
 
 def client_with(records, stop):
@@ -40,12 +41,12 @@ def test_a_request_cut_short_counts_in_tpot_over_its_tokens():
 
 @pytest.mark.parametrize("failed,correct", [(0, True), (1, False)])
 def test_a_failed_request_makes_the_run_not_correct(failed, correct):
-    s = R.Spec.from_config(tiny_config("internlm2-1.8b", TINY))
-    w = R.make_weights(s, 3)
+    s = G.spec(tiny_config("internlm2-1.8b", TINY))
+    w = G.make_weights(s, 3)
     prompt = np.arange(1, 9, dtype=np.int32)
-    lg = R.logits_at(w, s, prompt[None], [[7]])
+    lg = R.logits_at(G.logits, w, s, prompt[None], [[7]])
     best = [int(np.asarray(lg)[0, 0].argmax())]
-    v = check.compare(w, s, [prompt], [best], 0.3, failed)
+    v = check.compare(G, w, s, [prompt], [best], 0.3, failed)
     assert v["checks"]["max_gap"]["value"] == 0.0
     assert v["checks"]["failed"] == {"value": failed, "limit": 0}
     assert v["correct"] is correct

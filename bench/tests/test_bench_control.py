@@ -10,6 +10,7 @@ import numpy as np
 from bench_tiny import tiny_config
 from bench import check
 from bench import reference as R
+from bench.blocks import dense_gqa as G
 
 SMALL = {"num_hidden_layers": 8, "hidden_size": 512,
          "intermediate_size": 1024, "num_attention_heads": 8,
@@ -24,7 +25,7 @@ def greedy(w, s, prompt, n):
     toks[0, :len(prompt)] = prompt
     out = []
     for k in range(len(prompt), len(prompt) + n):
-        lg = R.logits_at(w, s, toks, [[k - 1]])
+        lg = R.logits_at(G.logits, w, s, toks, [[k - 1]])
         out.append(int(np.asarray(lg)[0, 0].argmax()))
         toks[0, k] = out[-1]
     return out
@@ -33,13 +34,13 @@ def greedy(w, s, prompt, n):
 def test_the_float8_control_fails_the_limit():
     conf = tiny_config("internlm2-1.8b", SMALL)
     limit = conf["check"]["max_gap"]
-    s = R.Spec.from_config(conf)
-    w = R.make_weights(s, 21)
+    s = G.spec(conf)
+    w = G.make_weights(s, 21)
     rng = np.random.default_rng(21)
     prompts = [rng.integers(0, s.vocab, PROMPT, dtype=np.int32)
                for _ in range(4)]
     outputs = [greedy(w, s, p, OUT) for p in prompts]
-    ok = check.compare(w, s, prompts, outputs, limit, 0, control=True)
+    ok = check.compare(G, w, s, prompts, outputs, limit, 0, control=True)
     assert ok["correct"] is True
     # the control in the program's place: at each position of the same
     # prompts and tokens it serves the token float8 puts first
@@ -47,8 +48,9 @@ def test_the_float8_control_fails_the_limit():
     for p, o in zip(prompts, outputs):
         seq = np.concatenate([p, np.asarray(o[:-1], np.int32)])[None]
         at = (len(p) - 1 + np.arange(len(o)))[None]
-        served.append(np.asarray(R.logits_at(w, s, seq, at, fp8=True))
+        served.append(np.asarray(R.logits_at(G.logits, w, s, seq, at,
+                                             fp8=True))
                       [0].argmax(-1).tolist())
-    bad = check.compare(w, s, prompts, served, limit, 0)
+    bad = check.compare(G, w, s, prompts, served, limit, 0)
     assert bad["correct"] is False
     assert ok["control_max_gap"] > limit

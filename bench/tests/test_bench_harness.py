@@ -115,6 +115,101 @@ def test_discovery_finds_new_files_by_name(tmp_path):
             assert open(p, "rb").read() == data, p
 
 
+@pytest.mark.parametrize("fault", ["none", "token_altered"])
+def test_a_new_block_enters_as_new_files_and_its_cell_runs(tmp_path, fault,
+                                                           monkeypatch):
+    """A second architecture block (LayerNorm, GELU MLP, tied head), its
+    configuration, mix and cell go into a copy of the benchmark as new files
+    and entries only, and a run of the cell on the CPU is correct against
+    that block's reference; with a token altered where it is sampled, it is
+    not.  No file that was there changes."""
+    from bench.registry import Registry
+    from bench_tiny import MIX, TINY, run_tiny, tiny_config
+    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    before = {p: open(p, "rb").read() for p in
+              (str(x) for x in tmp_path.rglob("*") if x.is_file())}
+    shutil.copy(os.path.join(ROOT, "bench", "tests", "extra_blocks",
+                             "layernorm_gelu_tied.py"),
+                tmp_path / "bench/blocks/layernorm_gelu_tied.py")
+    conf = tiny_config("internlm2-1.8b", dict(
+        TINY, norm="layernorm", mlp="gelu_mlp", tie_word_embeddings=True))
+    conf.update(name="tiny-lgt", block="layernorm_gelu_tied")
+    (tmp_path / "bench/configs/tiny-lgt.json").write_text(json.dumps(conf))
+    (tmp_path / "bench/traffic/tiny-mix.json").write_text(json.dumps(MIX))
+    b = bench_json()
+    b["configs"].append({"name": "tiny-lgt", "source": "x",
+                         "file": "bench/configs/tiny-lgt.json",
+                         "reduced": [], "why": "x"})
+    b["workloads"].append({"name": "tiny-lgt-chat", "config": "tiny-lgt",
+                           "traffic": "tiny-mix", "chips": 1, "why": "x"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    reg = Registry(str(tmp_path), str(tmp_path / "bench"))
+    assert reg.block(reg.config("tiny-lgt")).__file__ == str(
+        tmp_path / "bench/blocks/layernorm_gelu_tied.py")
+    if fault == "token_altered":
+        from test_bench_faults import FAULTS
+        FAULTS[fault](monkeypatch)
+    res = run_tiny(reg, "tiny-lgt-chat", seed=2**31 + 5)
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert set(res["metrics"]) == {"setup_s"}
+    gap = res["checks"]["max_gap"]
+    if fault == "none":
+        assert res["correct"] is True and gap["value"] < 1e-3
+    else:
+        assert res["correct"] is False and gap["value"] > gap["limit"]
+    for p, data in before.items():
+        if not p.endswith("BENCHMARK.json"):
+            assert open(p, "rb").read() == data, p
+
+
+def test_readers_get_only_what_the_engine_counted_while_traced(
+        tiny_registry, monkeypatch):
+    """``ctx.counters`` holds what the window's engine counted over the
+    traced steps alone: a count made in a step outside the trace, before it
+    or after it, does not reach it."""
+    from bench import run, serving_adapter as sa, work
+    monkeypatch.setattr(work, "peaks", lambda kind: {
+        "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11})
+    inside, steps, seen, last = [False], {"in": 0, "out": 0}, [], []
+    start, stop, step = sa.start_trace, sa.stop_trace, sa.Server.step
+
+    def traced_start(log_dir):
+        start(log_dir)
+        inside[0] = True
+
+    def traced_stop():
+        inside[0] = False
+        stop()
+
+    def counted_step(self):
+        where = "in" if inside[0] else "out"
+        self.metrics.counter("test." + where).inc()
+        steps[where] += 1
+        last[:] = [self]
+        step(self)
+
+    class Ctx(run.TraceCtx):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            seen.append(self.counters)
+
+    monkeypatch.setattr(sa, "start_trace", traced_start)
+    monkeypatch.setattr(sa, "stop_trace", traced_stop)
+    monkeypatch.setattr(sa.Server, "step", counted_step)
+    monkeypatch.setattr(run, "TraceCtx", Ctx)
+    res = run_tiny(tiny_registry, CELLS[0], trace=1)
+    assert res["correct"] is True
+    (counts,) = seen
+    window = last[0].metrics
+    assert window.counter("test.out").value > 0     # steps outside the trace
+    assert steps["in"] > 0 and counts["test.in"] == steps["in"]
+    assert counts["test.out"] == 0
+    assert 0 < counts["engine.steps"] < window.counter("engine.steps").value
+    assert not any(k.endswith((".mean", ".p95")) for k in counts)
+
+
 def test_run_without_a_tpu_exits_non_zero_with_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)

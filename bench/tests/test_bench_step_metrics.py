@@ -8,7 +8,7 @@ from bench import trace as T
 from bench.registry import Registry
 from bench.run import TraceCtx
 
-NO_TRACE = TraceCtx(None, {}, None, [], (0.0, 0))
+NO_TRACE = TraceCtx(None)
 
 
 def ev(name, start, dur):
@@ -24,7 +24,7 @@ def ctx(host, window=(0, 1000)):
     ``bench_window`` spans ``window``."""
     lo, hi = window
     tr = T.Trace({}, [ev(T.WINDOW_SPAN, lo, hi - lo)] + list(host))
-    return TraceCtx(None, {}, tr, [], (0.0, 0))
+    return TraceCtx(tr)
 
 
 # ----------------------------------------------------------------------

@@ -113,7 +113,7 @@ def test_trace_context_reads_busy_idle_kernels_and_programs():
                            80, 10)],
            T.MODULES_LINE: [ev("jit_paged_loop_fn(1)", 0, 60)]}
     tr = T.Trace({"/device:TPU:0": dev}, [ev(T.WINDOW_SPAN, 0, 100)])
-    ctx = TraceCtx(None, {}, tr, [], (0.0, 0))
+    ctx = TraceCtx(tr)
     assert ctx.window_s == pytest.approx(100e-9)
     assert ctx.busy_s() == pytest.approx(60e-9)
     assert ctx.idle_pct() == pytest.approx(40.0)

@@ -9,7 +9,8 @@ Planes named ``/device:...`` are devices; on a TPU their ``XLA Ops`` line
 holds one event per executed op (a Pallas kernel is one op) and their ``XLA
 Modules`` line one event per executed program.  Host planes hold the
 ``TraceAnnotation`` spans, among them the harness's ``bench_window``, which
-sets the traced window, and the program's ``prefill`` and ``decode_loop``.
+sets the traced window, and the program's step spans (``engine.step``,
+``engine.admit``, ``engine.prefill``, ``engine.decode_sync`` and the rest).
 """
 from __future__ import annotations
 
